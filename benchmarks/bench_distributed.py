@@ -88,7 +88,7 @@ def run() -> dict:
         [sys.executable, "-c", _SRC], capture_output=True, text=True,
         timeout=900,
         env={"PYTHONPATH": "src", "PATH": os.environ.get("PATH", ""),
-             "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")})
+             "JAX_PLATFORMS": "cpu"})
     for line in r.stdout.splitlines():
         if line.startswith("RESULT"):
             out = json.loads(line[len("RESULT"):])

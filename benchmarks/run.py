@@ -1,5 +1,6 @@
 """Benchmark orchestrator: one module per paper table/figure + systems
 benches. ``PYTHONPATH=src python -m benchmarks.run [--only a,b]``.
+A bench that raises makes the run exit non-zero.
 
 Each bench returns a dict with a ``claim_holds`` verdict tying the
 measurement back to the paper's statement; the summary table at the end is
@@ -143,16 +144,19 @@ def main() -> None:
                          "on a >20%% regression of any claim metric")
     args = ap.parse_args()
 
+    from repro.utils import compile_cache
+
+    compile_cache.enable()
+
     # with REPRO_OBS=on each bench row grows a ``telemetry`` section (the
     # registry delta across the bench: CG iterations, fallbacks, spans);
     # --check skips the subtree, so telemetry never gates perf
-    try:
-        from repro.obs import trace as obs
-        obs_on = obs.enabled()
-    except ImportError:     # benches runnable without src on the path
-        obs, obs_on = None, False
+    from repro.obs import trace as obs
+
+    obs_on = obs.enabled()
 
     results = {}
+    raised = []
     for key, module, desc in BENCHES:
         if args.only and key not in args.only.split(","):
             continue
@@ -170,6 +174,7 @@ def main() -> None:
         except Exception as e:  # noqa: BLE001
             results[key] = {"error": str(e), "claim_holds": False,
                             "_trace": traceback.format_exc()[-1500:]}
+            raised.append(key)
             print(f"ERROR {e}", flush=True)
     if obs_on:
         obs.flush()     # final registry snapshot into the JSONL sink
@@ -209,7 +214,9 @@ def main() -> None:
                     json.dump(results[key], f, indent=1, default=str)
     n_fail = sum(1 for r in results.values() if not r.get("claim_holds"))
     print(f"\n{len(results) - n_fail}/{len(results)} claims hold")
-    if (args.strict and n_fail) or regressions:
+    if raised:
+        print(f"benches that raised: {', '.join(raised)}")
+    if raised or (args.strict and n_fail) or regressions:
         raise SystemExit(1)
 
 

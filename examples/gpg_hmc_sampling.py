@@ -12,6 +12,9 @@ import jax.numpy as jnp
 
 from repro.hyper import HyperParams
 from repro.sampling import banana_energy, gpg_hmc, hmc
+from repro.utils import compile_cache
+
+compile_cache.enable()
 
 D = 100
 fourth = math.ceil(D ** 0.25)

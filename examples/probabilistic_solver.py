@@ -11,6 +11,9 @@ import numpy as np
 
 from repro.linalg import (cg_solve, hessian_probabilistic_solver,
                           make_test_matrix, solution_probabilistic_solver)
+from repro.utils import compile_cache
+
+compile_cache.enable()
 
 D = 100
 A = make_test_matrix(D)                    # App. F.1 spectrum, kappa = 200

@@ -14,6 +14,9 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 
 from repro.core import GPGState
+from repro.utils import compile_cache
+
+compile_cache.enable()
 
 D = 10_000                   # dimension — the axis the paper makes cheap
 N = 8                        # gradient observations (low-data regime N < D)

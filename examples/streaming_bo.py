@@ -50,6 +50,9 @@ from jax.scipy.special import erf
 
 from repro.core import GPGState
 from repro.train.serve import build_gp_serve_step
+from repro.utils import compile_cache
+
+compile_cache.enable()
 
 SMOKE = "--smoke" in sys.argv
 CHAOS = "--chaos" in sys.argv

@@ -21,6 +21,9 @@ from repro.optim import get_optimizer
 from repro.runtime import RecoveryConfig, run_with_recovery
 from repro.train import build_train_step
 from repro.models.registry import SHAPES, ShapeSpec
+from repro.utils import compile_cache
+
+compile_cache.enable()
 
 
 def main() -> None:

@@ -11,10 +11,15 @@ so the core inference engine never spells out those contractions in raw
     correctness reference everywhere).
 
 Resolution order: ``set_backend()``/``use_backend()`` > the
-``REPRO_BACKEND`` env var > auto (pallas on TPU, jnp elsewhere). The jnp
-path accumulates in the input dtype; the pallas path accumulates in f32
-(the TPU-native contract) — callers that need x64 semantics must be on the
-jnp backend, which is the auto default everywhere x64 exists.
+``REPRO_BACKEND`` env var (any value other than ``jnp``/``pallas`` raises
+``ValueError``) > auto (pallas on TPU, jnp elsewhere). The jnp path
+accumulates in the input dtype; the pallas path takes float32 or bfloat16
+(N, D) operands and accumulates in f32 (the TPU-native contract).  A
+float64 operand on the pallas path raises ``TypeError`` naming the op and
+the dtype — it is never rerouted or downcast.  x64 therefore belongs to
+the jnp oracles: on a TPU the auto backend is pallas, so a state built
+with x64 on must pass ``dtype=jnp.float32`` (or run under
+``use_backend("jnp")``).
 
 The functions here are the complete vocabulary of O(ND) work in the solve
 path: if a core module multiplies something (N, D)-shaped outside this
@@ -46,7 +51,10 @@ def resolve_backend() -> str:
     if _FORCED is not None:
         return _FORCED
     env = os.environ.get("REPRO_BACKEND", "").strip().lower()
-    if env in _VALID:
+    if env:
+        if env not in _VALID:
+            raise ValueError(f"REPRO_BACKEND must be one of {_VALID} or "
+                             f"unset, got {env!r}")
         return env
     return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
@@ -70,8 +78,22 @@ def use_backend(name: str) -> Iterator[None]:
         set_backend(prev)
 
 
-def _pallas() -> bool:
-    return resolve_backend() == "pallas"
+def _pallas(op: str, *operands) -> bool:
+    """True when ``op`` runs on the Pallas kernels.  Those take float32 or
+    bfloat16 streams only: a float64 operand raises instead of reaching a
+    kernel (Mosaic has no 64-bit types) or being downcast behind the
+    caller's back.  ``lam``/``v_scale`` are exempt — the kernel wrappers
+    cast those per-lane scales to f32 explicitly."""
+    if resolve_backend() != "pallas":
+        return False
+    for a in operands:
+        dt = getattr(a, "dtype", None)
+        if dt is not None and jnp.dtype(dt) == jnp.float64:
+            raise TypeError(
+                f"backend.{op}: the pallas backend takes float32/bfloat16 "
+                f"operands, got {jnp.dtype(dt).name} {tuple(a.shape)}; build "
+                f"the data in float32 or run under use_backend('jnp')")
+    return True
 
 
 # ---------------------------------------------------------------------------
@@ -143,7 +165,7 @@ def _acc(x: Array) -> Array:
 
 def scaled_gram(A: Array, B: Array, lam) -> Array:
     """(N_a, N_b) matrix  A Lambda B^T — THE hot contraction of the method."""
-    if _pallas():
+    if _pallas("scaled_gram", A, B):
         return _k.skinny_gram(A, B, lam)
     A, B = _acc(A), _acc(B)
     return (A * lam) @ B.T
@@ -151,7 +173,7 @@ def scaled_gram(A: Array, B: Array, lam) -> Array:
 
 def gram_norms(A: Array, B: Array, lam):
     """(P, |A|^2_lam rowwise, |B|^2_lam rowwise) in one logical pass."""
-    if _pallas():
+    if _pallas("gram_norms", A, B):
         return _k.fused_gram_norms(A, B, lam)
     A, B = _acc(A), _acc(B)
     P = (A * lam) @ B.T
@@ -171,7 +193,7 @@ def fused_factor_build(A: Array, B: Array, V: Array | None, lam, *,
     spells out the same contractions (XLA is free to fuse them, and the
     x64 oracle semantics are preserved for f32/f64 inputs).
     """
-    if _pallas():
+    if _pallas("fused_factor_build", A, B, V):
         return _k.fused_factor_build(A, B, V, lam, v_scale=v_scale)
     A, B = _acc(A), _acc(B)
     V = B if V is None else _acc(V)
@@ -210,7 +232,7 @@ def gram_update(K1: Array, small: Array, V: Array, X: Array, lam, *,
     The D-streaming half of Alg. 2 and the workhorse of every exact solve:
     Woodbury's final assembly runs it with v_scale = 1/lam, lam = 1.
     """
-    if _pallas():
+    if _pallas("gram_update", K1, small, V, X):
         return _k.gram_update(K1, small, V, X, lam, v_scale=v_scale,
                               noise=noise)
     V, X = _acc(V), _acc(X)
@@ -226,7 +248,7 @@ def kron_precond(K1i: Array, V: Array, lam) -> Array:
 
     V may be (N, D) or stacked (R, N, D); K1i is the (N, N) inverse factor.
     """
-    if _pallas() and V.ndim == 2:
+    if V.ndim == 2 and _pallas("kron_precond", K1i, V):
         return _k.small_matmul(K1i, V, 1.0 / jnp.asarray(lam))
     return (_acc(K1i) @ _acc(V)) / lam
 
@@ -240,7 +262,7 @@ def fused_gram_mvm(K1e: Array, K2e: Array, Xt: Array, V: Array, lam, *,
     jnp: the einsum oracle in f32 accumulation. V (N, D) or stacked
     (R, N, D); the stacked form amortizes the Xt stream across RHS.
     """
-    if _pallas():
+    if _pallas("fused_gram_mvm", K1e, K2e, Xt, V):
         return _k.fused_gram_mvm(K1e, K2e, Xt, V, lam, stationary=stationary,
                                  noise=noise)
     # Native-dtype oracle (keeps x64 precision; broadcast over stacked RHS);

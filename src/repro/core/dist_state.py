@@ -61,6 +61,7 @@ strip), and serves posterior mean value/grad batches.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -72,7 +73,7 @@ from repro.obs import compile_watch as _cw
 from repro.obs import trace as _obs
 
 from . import backend
-from .distributed import _shard_map, ring_psum
+from .distributed import psum_fused, ring_psum
 from .gram import GramFactors
 from .kernels import KernelSpec, get_kernel
 from .mvm import l_op, lt_op
@@ -106,6 +107,24 @@ class SGPGData(NamedTuple):
     @property
     def count(self) -> Array:
         return self.base.count
+
+
+def _f32_matmuls(fn):
+    """Trace ``fn`` with its float32 matmuls at full float32 precision.
+
+    A TPU contracts float32 operands in one bf16 pass unless told
+    otherwise.  The sharded state solves directly: no CG step measures and
+    corrects the residual, as the single-device state's does, so a bf16
+    pass in the replicated (N, N) algebra reaches Z and the posterior
+    (value 3.4e-3 off a float64 oracle on four v5e chips at d = 2**25).
+    The D-long contractions are Pallas kernels with their own precision.
+    """
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+
+    return wrapped
 
 
 def sgpg_init(spec: KernelSpec, d: int, capacity: int, *, lam=1.0,
@@ -146,6 +165,7 @@ def _r_from_strips(spec: KernelSpec, S0: Array, lam) -> Array:
     return lam * S0
 
 
+@_f32_matmuls
 def sgpg_direct_solve(
     spec: KernelSpec,
     data: SGPGData,
@@ -224,6 +244,7 @@ def sgpg_direct_solve(
 # ---------------------------------------------------------------------------
 
 
+@_f32_matmuls
 def sgpg_extend(
     spec: KernelSpec,
     data: SGPGData,
@@ -267,7 +288,7 @@ def sgpg_extend(
         parts = parts + (backend.scaled_gram(rhs, Xt_p, 1.0),)
     if extra_partials is not None:
         parts = parts + (extra_partials,)
-    parts = jax.lax.psum(parts, axis_names)     # the ONE extend collective
+    parts = psum_fused(parts, axis_names)       # the ONE extend collective
     S2, G2 = parts[0], parts[1]
     C_rhs = parts[2] if rhs is not None else None
     extras = parts[-1] if extra_partials is not None else None
@@ -317,6 +338,7 @@ def sgpg_extend(
     return data, extras
 
 
+@_f32_matmuls
 def sgpg_evict(
     spec: KernelSpec,
     data: SGPGData,
@@ -342,6 +364,7 @@ def sgpg_evict(
     return data
 
 
+@_f32_matmuls
 def sgpg_refactor(
     spec: KernelSpec,
     data: SGPGData,
@@ -373,6 +396,7 @@ def sgpg_refactor(
     return data
 
 
+@_f32_matmuls
 def sgpg_resolve(
     spec: KernelSpec,
     data: SGPGData,
@@ -391,6 +415,7 @@ def sgpg_resolve(
                              rhs=rhs, C_rhs=C_rhs)
 
 
+@_f32_matmuls
 def sgpg_rebuild(
     spec: KernelSpec,
     data: SGPGData,
@@ -409,11 +434,12 @@ def sgpg_rebuild(
     G = jnp.where(mask[:, None], b.G, 0.0)
     P_, _, _, C, _ = backend.fused_factor_build(Xt, Xt, G, 1.0)
     GGp = backend.scaled_gram(G, G, 1.0)
-    S0, C, GG = jax.lax.psum((P_, C, GGp), axis_names)
+    S0, C, GG = psum_fused((P_, C, GGp), axis_names)
     data = data._replace(base=b._replace(Xt=Xt, G=G), S0=S0, C=C, GG=GG)
     return sgpg_refactor(spec, data, noise=noise, jitter=jitter, solve=solve)
 
 
+@_f32_matmuls
 def sgpg_posterior_mean(
     spec: KernelSpec,
     data: SGPGData,
@@ -433,10 +459,11 @@ def sgpg_posterior_mean(
     if not spec.is_stationary and b.c is not None:
         Xq = Xq - b.c
     f = GramFactors(K1e=b.K1e, K2e=b.K2e, Xt=b.Xt, lam=b.lam, c=None)
-    strips = jax.lax.psum(_mean_strips(Xq, f, b.Z), axis_names)
+    strips = psum_fused(_mean_strips(Xq, f, b.Z), axis_names)
     return _mean_assemble(spec, strips, Xq, f, b.Z)
 
 
+@_f32_matmuls
 def sgpg_posterior_mean_pipelined(
     spec: KernelSpec,
     data: SGPGData,
@@ -591,10 +618,23 @@ class ShardedGPGState:
         cap = self.window if self.window else int(capacity)
         if c is not None:
             c = jnp.pad(jnp.asarray(c, dtype), (0, self.d_pad - self.d_orig))
-        self.data = sgpg_init(self.spec, self.d_pad, cap, lam=lam, c=c,
-                              dtype=dtype)
+        self._has_c = c is not None and not self.spec.is_stationary
+        # built in place with the phase programs' shardings: the (cap, D)
+        # strips never sit whole on one device, and the first extend sees
+        # the same input shardings as every later one (no retrace)
+        from jax.sharding import NamedSharding
+
+        shardings = jax.tree_util.tree_map(
+            lambda s: NamedSharding(mesh, s), self._data_spec(),
+            is_leaf=lambda s: isinstance(s, P))
+        spec = self.spec
+        self.data = jax.jit(
+            lambda c: sgpg_init(spec, self.d_pad, cap, lam=lam, c=c,
+                                dtype=dtype),
+            out_shardings=shardings)(c)
         self.revision = 0
         self._fns: dict = {}
+        self._phase_raws: dict = {}
         self._query_fns: dict = {}
         self._query_raws: dict = {}
         if _obs.enabled():
@@ -603,10 +643,9 @@ class ShardedGPGState:
     # -- compiled phase programs (built once per shape) --------------------
 
     def _data_spec(self) -> SGPGData:
-        has_c = self.data.base.c is not None
         r = P()
-        return SGPGData(base=_base_specs(self._names, has_c), S0=r, C=r,
-                        GG=r)
+        return SGPGData(base=_base_specs(self._names, self._has_c), S0=r,
+                        C=r, GG=r)
 
     def _phase(self, name: str):
         """The compiled shard_map program for one phase (cached)."""
@@ -649,11 +688,18 @@ class ShardedGPGState:
         else:
             raise KeyError(name)
 
-        sm = _shard_map(raw, mesh=self.mesh, in_specs=in_specs,
-                        out_specs=dspec, check_rep=False)
+        sm = jax.shard_map(raw, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=dspec, check_vma=False)
         fn = _cw.wrap(sm, name=f"distributed.{name}")
         self._fns[name] = fn
+        self._phase_raws[name] = sm
         return fn
+
+    def _phase_raw(self, name: str):
+        """The UNWRAPPED shard_map program of a phase (for jaxpr/HLO
+        inspection — tracing it never touches the compile watch)."""
+        self._phase(name)
+        return self._phase_raws[name]
 
     def _query_fn(self, q: int, chunks: Optional[int]):
         key = (q, chunks)
@@ -677,8 +723,8 @@ class ShardedGPGState:
                 return sgpg_posterior_mean_pipelined(
                     spec, data, Xq, axis_name=axis, axis_size=size,
                     chunks=chunks)
-        sm = _shard_map(raw, mesh=self.mesh, in_specs=(dspec, dn),
-                        out_specs=(P(), dn), check_rep=False)
+        sm = jax.shard_map(raw, mesh=self.mesh, in_specs=(dspec, dn),
+                           out_specs=(P(), dn), check_vma=False)
         fn = _cw.wrap(sm, name=f"distributed.query.q{q}"
                       + (f".pipe{chunks}" if chunks else ""))
         self._query_fns[key] = fn

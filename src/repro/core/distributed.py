@@ -24,11 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from . import backend
 from .gram import FactorBundle, GramFactors
 from .kernels import KernelSpec
@@ -40,6 +35,28 @@ Array = jnp.ndarray
 # ---------------------------------------------------------------------------
 # Collective-side primitives (called inside shard_map)
 # ---------------------------------------------------------------------------
+
+def psum_fused(x, axis_names):
+    """``jax.lax.psum`` of a pytree as ONE collective.
+
+    ``jax.lax.psum`` binds one psum per leaf, so a tuple of strips would
+    cost one all-reduce launch each.  This packs the leaves into one flat
+    buffer of their common dtype, reduces it once and unpacks — the
+    one-psum-per-phase contract of ``core/dist_state.py`` (gated by
+    ``utils.hlo.count_psums``) holds at the jaxpr level.
+    """
+    leaves, treedef = jax.tree_util.tree_flatten(x)
+    if len(leaves) <= 1:
+        return jax.lax.psum(x, axis_names)
+    dt = jnp.result_type(*leaves)
+    flat = jax.lax.psum(
+        jnp.concatenate([jnp.ravel(a).astype(dt) for a in leaves]), axis_names)
+    out, off = [], 0
+    for a in leaves:
+        out.append(flat[off:off + a.size].reshape(a.shape).astype(a.dtype))
+        off += a.size
+    return jax.tree_util.tree_unflatten(treedef, out)
+
 
 def ring_psum(x, axis_name: str, size: int):
     """All-reduce built from ``size - 1`` ppermute ring hops (pytree-safe).
@@ -80,7 +97,7 @@ def local_pairwise_r(
     """Pairwise r for D-sharded inputs; one fused psum of (gram, norms)."""
     if spec.is_stationary:
         part, da, db = backend.gram_norms(A, B, lam)
-        g, da, db = jax.lax.psum((part, da, db), axis_names)
+        g, da, db = psum_fused((part, da, db), axis_names)
         return jnp.maximum(da[:, None] + db[None, :] - 2.0 * g, 0.0)
     At = A if c is None else A - c
     Bt = B if c is None else B - c
@@ -127,7 +144,7 @@ def local_factor_bundle(
     """
     Xt = X if (spec.is_stationary or c is None) else X - c
     P_, na, nb, C, _ = backend.fused_factor_build(Xt, Xt, G, lam)
-    P_, na, C = jax.lax.psum((P_, na, C), axis_names)
+    P_, na, C = psum_fused((P_, na, C), axis_names)
     if spec.is_stationary:
         r = jnp.maximum(na[:, None] + na[None, :] - 2.0 * P_, 0.0)
     else:
@@ -225,7 +242,7 @@ def sharded_gram_matvec(mesh: Mesh, spec: KernelSpec):
     lam_spec = P()  # scalar lam replicated; diagonal handled by caller
 
     @partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(None, None), P(None, None), dspec, lam_spec, dspec),
         out_specs=dspec,
     )
@@ -258,7 +275,7 @@ def sharded_factor_bundle(mesh: Mesh, spec: KernelSpec, noise: float = 0.0):
         return f.K1e, f.K2e, f.Xt, b.S, b.C
 
     @partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(dspec, dspec, P()),
         out_specs=out,
     )
@@ -267,7 +284,7 @@ def sharded_factor_bundle(mesh: Mesh, spec: KernelSpec, noise: float = 0.0):
                                            noise=noise))
 
     @partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(dspec, dspec, P(), dspec),
         out_specs=out,
     )
@@ -307,7 +324,7 @@ def sharded_woodbury_solve(mesh: Mesh, spec: KernelSpec, noise: float = 0.0):
     rep = P(None, None)
 
     @partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(dspec, dspec, P()),
         out_specs=dspec,
     )
@@ -316,7 +333,7 @@ def sharded_woodbury_solve(mesh: Mesh, spec: KernelSpec, noise: float = 0.0):
         return local_woodbury_solve(spec, b.factors, G, names, S=b.S, C=b.C)
 
     @partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(dspec, dspec, P(), dspec),
         out_specs=dspec,
     )
@@ -325,7 +342,7 @@ def sharded_woodbury_solve(mesh: Mesh, spec: KernelSpec, noise: float = 0.0):
         return local_woodbury_solve(spec, b.factors, G, names, S=b.S, C=b.C)
 
     @partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(rep, rep, dspec, P(), rep, rep, dspec),
         out_specs=dspec,
     )

@@ -43,6 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ._mosaic import HIGHEST, block
+
 Array = jnp.ndarray
 
 
@@ -63,13 +65,15 @@ def _kernel(a_ref, b_ref, v_ref, lam_ref, vs_ref,
     al = a * lam
     bl = b * lam
     p_ref[...] += jax.lax.dot_general(
-        al, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        al, b, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32
     )
     na_ref[...] += jnp.sum(al * a, axis=1, keepdims=True)
     nb_ref[...] += jnp.sum(bl * b, axis=1, keepdims=True)
     c_ref[...] += jax.lax.dot_general(
         v * vs_ref[...].astype(jnp.float32), a,
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32
     )
     tv_ref[...] += jnp.sum(bl * v, axis=1, keepdims=True)
 
@@ -92,18 +96,18 @@ def fused_factor_build_padded(
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((na_, block_d), lambda i: (0, i)),
-            pl.BlockSpec((nb_, block_d), lambda i: (0, i)),
-            pl.BlockSpec((nb_, block_d), lambda i: (0, i)),
-            pl.BlockSpec((1, block_d), lambda i: (0, i)),
-            pl.BlockSpec((1, block_d), lambda i: (0, i)),
+            block((na_, block_d), lambda i: (0, i)),
+            block((nb_, block_d), lambda i: (0, i)),
+            block((nb_, block_d), lambda i: (0, i)),
+            block((1, block_d), lambda i: (0, i)),
+            block((1, block_d), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((na_, nb_), lambda i: (0, 0)),
-            pl.BlockSpec((na_, 1), lambda i: (0, 0)),
-            pl.BlockSpec((nb_, 1), lambda i: (0, 0)),
-            pl.BlockSpec((nb_, na_), lambda i: (0, 0)),
-            pl.BlockSpec((nb_, 1), lambda i: (0, 0)),
+            block((na_, nb_), lambda i: (0, 0)),
+            block((na_, 1), lambda i: (0, 0)),
+            block((nb_, 1), lambda i: (0, 0)),
+            block((nb_, na_), lambda i: (0, 0)),
+            block((nb_, 1), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((na_, nb_), jnp.float32),

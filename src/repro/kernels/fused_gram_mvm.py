@@ -44,6 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._mosaic import HIGHEST, block
+
 Array = jnp.ndarray
 
 from .gram_update import _out_dtype  # bf16 storage in -> f32 out
@@ -84,7 +86,8 @@ def _kernel(k1_ref, k2_ref, x_ref, v_ref, lam_ref, o_ref, m_ref,
         xl = x_ref[...].astype(jnp.float32) * lam_ref[...].astype(jnp.float32)
         v = v_ref[...].astype(jnp.float32)
         m_ref[...] += jax.lax.dot_general(
-            xl, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            xl, v, (((1,), (1,)), ((), ())), precision=HIGHEST,
+            preferred_element_type=jnp.float32
         )
 
     @pl.when((p == 1) & (j == 0))
@@ -97,8 +100,10 @@ def _kernel(k1_ref, k2_ref, x_ref, v_ref, lam_ref, o_ref, m_ref,
         k1 = k1_ref[...].astype(jnp.float32)
         v = v_ref[...].astype(jnp.float32)
         x = x_ref[...].astype(jnp.float32)
-        acc = jnp.dot(k1, v, preferred_element_type=jnp.float32)
-        acc += jnp.dot(m_ref[...], x, preferred_element_type=jnp.float32)
+        acc = jnp.dot(k1, v, precision=HIGHEST,
+                      preferred_element_type=jnp.float32)
+        acc += jnp.dot(m_ref[...], x, precision=HIGHEST,
+                       preferred_element_type=jnp.float32)
         out = acc * lam_ref[...].astype(jnp.float32)
         if noise:
             out = out + jnp.float32(noise) * v
@@ -122,13 +127,13 @@ def fused_gram_mvm_padded(
         functools.partial(_kernel, stationary=stationary, noise=float(noise)),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((n, n), lambda p, j: (0, 0)),
-            pl.BlockSpec((n, n), lambda p, j: (0, 0)),
-            pl.BlockSpec((n, block_d), lambda p, j: (0, j)),
-            pl.BlockSpec((n, block_d), lambda p, j: (0, j)),
-            pl.BlockSpec((1, block_d), lambda p, j: (0, j)),
+            block((n, n), lambda p, j: (0, 0)),
+            block((n, n), lambda p, j: (0, 0)),
+            block((n, block_d), lambda p, j: (0, j)),
+            block((n, block_d), lambda p, j: (0, j)),
+            block((1, block_d), lambda p, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((n, block_d), lambda p, j: (0, j * p)),
+        out_specs=block((n, block_d), lambda p, j: (0, j * p)),
         out_shape=jax.ShapeDtypeStruct((n, d), _out_dtype(V.dtype)),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
@@ -150,7 +155,8 @@ def _kernel_multi(k1_ref, k2_ref, x_ref, v_ref, lam_ref, o_ref, m_ref,
         v = v_ref[...].astype(jnp.float32)
         # M[r, a, b] = sum_d (Xt*lam)[a, d] V[r, b, d]
         m_ref[...] += jax.lax.dot_general(
-            v, xl, (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            v, xl, (((2,), (1,)), ((), ())), precision=HIGHEST,
+            preferred_element_type=jnp.float32
         ).transpose(0, 2, 1)
 
     @pl.when((p == 1) & (j == 0))
@@ -165,10 +171,12 @@ def _kernel_multi(k1_ref, k2_ref, x_ref, v_ref, lam_ref, o_ref, m_ref,
         x = x_ref[...].astype(jnp.float32)
         # (R, N, bd): K1e @ V_r batches over r; small_r @ Xt batches over r.
         acc = jax.lax.dot_general(
-            v, k1, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            v, k1, (((1,), (1,)), ((), ())), precision=HIGHEST,
+            preferred_element_type=jnp.float32
         ).transpose(0, 2, 1)
         acc += jax.lax.dot_general(
             m_ref[...], x, (((2,), (0,)), ((), ())),
+            precision=HIGHEST,
             preferred_element_type=jnp.float32,
         )
         out = acc * lam_ref[...].astype(jnp.float32)
@@ -195,13 +203,13 @@ def fused_gram_mvm_multi_padded(
                           noise=float(noise)),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((n, n), lambda p, j: (0, 0)),
-            pl.BlockSpec((n, n), lambda p, j: (0, 0)),
-            pl.BlockSpec((n, block_d), lambda p, j: (0, j)),
-            pl.BlockSpec((r, n, block_d), lambda p, j: (0, 0, j)),
-            pl.BlockSpec((1, block_d), lambda p, j: (0, j)),
+            block((n, n), lambda p, j: (0, 0)),
+            block((n, n), lambda p, j: (0, 0)),
+            block((n, block_d), lambda p, j: (0, j)),
+            block((r, n, block_d), lambda p, j: (0, 0, j)),
+            block((1, block_d), lambda p, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((r, n, block_d), lambda p, j: (0, 0, j * p)),
+        out_specs=block((r, n, block_d), lambda p, j: (0, 0, j * p)),
         out_shape=jax.ShapeDtypeStruct((r, n, d), _out_dtype(V.dtype)),
         scratch_shapes=[pltpu.VMEM((r, n, n), jnp.float32)],
         interpret=interpret,

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ._mosaic import HIGHEST, block
+
 Array = jnp.ndarray
 
 
@@ -31,7 +33,8 @@ def _kernel(a_ref, b_ref, lam_ref, p_ref, na_ref, nb_ref):
     b = b_ref[...].astype(jnp.float32)
     al = a * lam
     p_ref[...] += jax.lax.dot_general(
-        al, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        al, b, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32
     )
     na_ref[...] += jnp.sum(al * a, axis=1, keepdims=True)
     nb_ref[...] += jnp.sum((b * lam) * b, axis=1, keepdims=True)
@@ -50,14 +53,14 @@ def fused_gram_norms_padded(
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((na_, block_d), lambda i: (0, i)),
-            pl.BlockSpec((nb_, block_d), lambda i: (0, i)),
-            pl.BlockSpec((1, block_d), lambda i: (0, i)),
+            block((na_, block_d), lambda i: (0, i)),
+            block((nb_, block_d), lambda i: (0, i)),
+            block((1, block_d), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((na_, nb_), lambda i: (0, 0)),
-            pl.BlockSpec((na_, 1), lambda i: (0, 0)),
-            pl.BlockSpec((nb_, 1), lambda i: (0, 0)),
+            block((na_, nb_), lambda i: (0, 0)),
+            block((na_, 1), lambda i: (0, 0)),
+            block((nb_, 1), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((na_, nb_), jnp.float32),

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ._mosaic import HIGHEST, block
+
 Array = jnp.ndarray
 
 
@@ -39,8 +41,10 @@ def _kernel(k1_ref, m_ref, v_ref, x_ref, lam_ref, vs_ref, o_ref, *, noise: float
     v = v_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)
     vs = v * vs_ref[...].astype(jnp.float32)
-    acc = jnp.dot(k1, vs, preferred_element_type=jnp.float32)
-    acc += jnp.dot(m, x, preferred_element_type=jnp.float32)
+    acc = jnp.dot(k1, vs, precision=HIGHEST,
+                  preferred_element_type=jnp.float32)
+    acc += jnp.dot(m, x, precision=HIGHEST,
+                   preferred_element_type=jnp.float32)
     out = acc * lam_ref[...].astype(jnp.float32)
     if noise:
         out = out + jnp.float32(noise) * v
@@ -50,7 +54,8 @@ def _kernel(k1_ref, m_ref, v_ref, x_ref, lam_ref, vs_ref, o_ref, *, noise: float
 def _small_matmul_kernel(k_ref, v_ref, s_ref, o_ref):
     k = k_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
-    out = jnp.dot(k, v, preferred_element_type=jnp.float32)
+    out = jnp.dot(k, v, precision=HIGHEST,
+                  preferred_element_type=jnp.float32)
     o_ref[...] = (out * s_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
@@ -73,11 +78,11 @@ def small_matmul_padded(
         _small_matmul_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((nq, n), lambda i: (0, 0)),
-            pl.BlockSpec((n, block_d), lambda i: (0, i)),
-            pl.BlockSpec((1, block_d), lambda i: (0, i)),
+            block((nq, n), lambda i: (0, 0)),
+            block((n, block_d), lambda i: (0, i)),
+            block((1, block_d), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((nq, block_d), lambda i: (0, i)),
+        out_specs=block((nq, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((nq, d), _out_dtype(V.dtype)),
         interpret=interpret,
     )(K, V, s2)
@@ -105,14 +110,14 @@ def gram_update_padded(
         functools.partial(_kernel, noise=float(noise)),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((nq, n), lambda i: (0, 0)),
-            pl.BlockSpec((nq, n), lambda i: (0, 0)),
-            pl.BlockSpec((n, block_d), lambda i: (0, i)),
-            pl.BlockSpec((n, block_d), lambda i: (0, i)),
-            pl.BlockSpec((1, block_d), lambda i: (0, i)),
-            pl.BlockSpec((1, block_d), lambda i: (0, i)),
+            block((nq, n), lambda i: (0, 0)),
+            block((nq, n), lambda i: (0, 0)),
+            block((n, block_d), lambda i: (0, i)),
+            block((n, block_d), lambda i: (0, i)),
+            block((1, block_d), lambda i: (0, i)),
+            block((1, block_d), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((nq, block_d), lambda i: (0, i)),
+        out_specs=block((nq, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((nq, d), _out_dtype(V.dtype)),
         interpret=interpret,
     )(K1, M, V, X, lam2, vs2)

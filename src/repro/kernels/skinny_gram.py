@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ._mosaic import HIGHEST, block
+
 Array = jnp.ndarray
 
 
@@ -33,7 +35,8 @@ def _kernel(a_ref, b_ref, lam_ref, o_ref):
     a = a_ref[...].astype(jnp.float32) * lam_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
     o_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32
     )
 
 
@@ -51,11 +54,11 @@ def skinny_gram_padded(
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((na, block_d), lambda i: (0, i)),
-            pl.BlockSpec((nb, block_d), lambda i: (0, i)),
-            pl.BlockSpec((1, block_d), lambda i: (0, i)),
+            block((na, block_d), lambda i: (0, i)),
+            block((nb, block_d), lambda i: (0, i)),
+            block((1, block_d), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((na, nb), lambda i: (0, 0)),
+        out_specs=block((na, nb), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((na, nb), jnp.float32),
         interpret=interpret,
     )(A, B, lam2)

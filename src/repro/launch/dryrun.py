@@ -33,10 +33,12 @@ from repro.optim import get_optimizer
 from repro.train import build_decode_step, build_prefill_step, build_train_step
 from repro.utils import roofline_terms
 from repro.utils.hlo_cost import analyze_hlo
-from repro.utils.roofline import TPUv5e
+from repro.utils.roofline import chip_for
 
 ASSIGNED_SHAPES = ("train_4k", "prefill_32k", "decode_32k", "long_500k")
 HBM_BYTES = 16e9            # v5e per-chip HBM
+# The dry run compiles on host devices and models the chip it targets.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 TRAIN_MICROBATCHES = 8
 
 
@@ -121,10 +123,11 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str) -> dict:
         hbm_bytes_opt = float(costs.bytes_out)  # optimistic (perfect fusion)
         xla_flops = float(cost.get("flops", 0.0))
         mf = model_flops_of(cfg, pa, shape_name)
+        chip = chip_for(TARGET_DEVICE_KIND)
         rt = roofline_terms(
             flops_per_device=flops, hbm_bytes_per_device=hbm_bytes,
             collective_bytes_per_device=coll_bytes, chips=chips,
-            model_flops=mf)
+            chip=chip, model_flops=mf)
         arg_b = float(mem.argument_size_in_bytes)
         tmp_b = float(mem.temp_size_in_bytes)
         out_b = float(mem.output_size_in_bytes)
@@ -139,7 +142,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str) -> dict:
             peak_bytes=peak, fits_hbm=bool(peak <= HBM_BYTES),
             flops_per_dev=flops, hbm_bytes_per_dev=hbm_bytes,
             hbm_bytes_opt_per_dev=hbm_bytes_opt,
-            memory_s_opt=hbm_bytes_opt / TPUv5e.hbm_bw,
+            memory_s_opt=hbm_bytes_opt / chip.hbm_bw,
             collective_bytes_per_dev=coll_bytes,
             collectives=coll, xla_flops_per_dev=xla_flops,
             model_flops=mf,

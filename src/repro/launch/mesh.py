@@ -6,24 +6,12 @@ asks for the mesh.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:  # jax >= 0.5: explicit/auto axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older jax has implicit Auto axes
-    AxisType = None
-
-_MAKE_MESH_TAKES_AXIS_TYPES = "axis_types" in inspect.signature(jax.make_mesh).parameters
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: pass axis_types only if supported."""
-    if AxisType is not None and _MAKE_MESH_TAKES_AXIS_TYPES:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -67,8 +67,11 @@ def record_measured(name: str, seconds: float, costs=None,
     ``costs`` is a ``Costs`` from :func:`modeled` (pass the same one the
     request was modeled with); with it, the achieved fraction of roofline
     — min-time-per-model / measured — is computed against ``chip``
-    (default ``utils.roofline.TPUv5e``) and published as
-    ``cost.<name>.roofline_fraction``.  Returns the fraction (or None).
+    (default: the peak-table entry of the TPU this process runs on,
+    ``utils.roofline.chip_for``, which raises for an unknown TPU) and
+    published as ``cost.<name>.roofline_fraction``.  Off a TPU, with no
+    explicit ``chip``, no fraction is published: a host wall time over a
+    TPU's peaks is not a roofline share.  Returns the fraction (or None).
     """
     if not _trace.enabled():
         return None
@@ -77,7 +80,14 @@ def record_measured(name: str, seconds: float, costs=None,
     frac = None
     if costs is not None and seconds > 0.0:
         if chip is None:
-            from repro.utils.roofline import TPUv5e as chip
+            import jax
+
+            from repro.utils.roofline import chip_for
+
+            dev = jax.devices()[0]
+            if dev.platform != "tpu":
+                return None
+            chip = chip_for(dev.device_kind)
         bound = max(costs.bytes_hbm / chip.hbm_bw,
                     costs.flops / chip.peak_flops)
         frac = bound / float(seconds)

@@ -38,7 +38,7 @@ from repro.core import GramFactors, get_kernel, infer_optimum, posterior_hessian
 from repro.core.dist_state import (SGPGData, _base_specs, sgpg_direct_solve,
                                    sgpg_evict, sgpg_extend, sgpg_init,
                                    sgpg_refactor)
-from repro.core.distributed import _shard_map
+from repro.core.distributed import psum_fused
 from repro.core.state import gpg_evict, gpg_extend, gpg_init, gpg_refactor
 from repro.hyper import (LENGTHSCALE_ONLY, HyperParams, fit_scan, fit_scan_fn,
                          make_mll_strips_fn)
@@ -372,7 +372,7 @@ def _gp_precond_sharded(
                         m_p = jnp.sum(x_t[None, :] * lam * b.Z, axis=-1)
                     Pl = jnp.concatenate([(Xtq * lam).T, (b.Z * lam).T],
                                          axis=1)
-                    r, mv, PtP, Ptg = jax.lax.psum(
+                    r, mv, PtP, Ptg = psum_fused(
                         (r_p, m_p, Pl.T @ Pl, Pl.T @ g_t), names)
                     if spec.is_stationary:
                         r = jnp.maximum(r, 0.0)
@@ -412,7 +412,7 @@ def _gp_precond_sharded(
                 else:
                     # fused: uphill-flip inner product + trust-region RMS
                     # (flip applied after the psum — RMS is flip-invariant)
-                    dg, ss = jax.lax.psum(
+                    dg, ss = psum_fused(
                         (jnp.vdot(d_, g_t), jnp.sum(d_f * d_f)), names)
                     d_f = jnp.where(dg > 0, -d_f, d_f)
                 rms = jnp.sqrt(ss / d_pad + 1e-30)
@@ -425,9 +425,9 @@ def _gp_precond_sharded(
         dspec = SGPGData(base=_base_specs(names, False), S0=P(), C=P(),
                          GG=P())
         vec = P(names)
-        sm = _shard_map(body, mesh=mesh,
-                        in_specs=(dspec, vec, vec, vec, P()),
-                        out_specs=(dspec, vec, vec), check_rep=False)
+        sm = jax.shard_map(body, mesh=mesh,
+                           in_specs=(dspec, vec, vec, vec, P()),
+                           out_specs=(dspec, vec, vec), check_vma=False)
         data, upd, m_buf = sm(state["gpg"], x_t, g_t, state["m"], step)
 
         prev = state["gpg"].base.count

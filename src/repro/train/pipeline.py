@@ -30,15 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# jax >= 0.6 tracks replicated-vs-varying types inside shard_map explicitly;
-# older jax treats everything as varying, so pvary is the identity there.
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
 Array = jnp.ndarray
 
 
@@ -64,7 +55,7 @@ def gpipe_forward(
         lambda _: P(stage_axis), stage_params)
 
     @partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),
     )
@@ -94,8 +85,9 @@ def gpipe_forward(
             return (outputs, nxt), None
 
         # initial carries must be marked device-varying along the stage axis
-        out0 = _pvary(jnp.zeros_like(x_all), (stage_axis,))
-        inflight0 = _pvary(jnp.zeros_like(x_all[0]), (stage_axis,))
+        out0 = jax.lax.pcast(jnp.zeros_like(x_all), (stage_axis,), to="varying")
+        inflight0 = jax.lax.pcast(jnp.zeros_like(x_all[0]), (stage_axis,),
+                                  to="varying")
         (outputs, _), _ = jax.lax.scan(tick, (out0, inflight0),
                                        jnp.arange(ticks))
         # outputs live on the last stage; broadcast to all members so the

@@ -85,9 +85,9 @@ def _axis_ge(aval, d: int) -> bool:
 
 
 def _is_var(v) -> bool:
-    import jax
+    from jax.extend.core import Literal
 
-    return not isinstance(v, jax.core.Literal)
+    return not isinstance(v, Literal)
 
 
 def _walk_streams(jaxpr, tainted: set, d: int, counts: dict) -> set:

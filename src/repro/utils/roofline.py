@@ -1,4 +1,4 @@
-"""Roofline-term calculator for dry-run compiled artifacts (TPU v5e target).
+"""Roofline-term calculator and the per-chip peak table.
 
 Three terms per (arch x mesh), each an estimated lower-bound execution time
 in seconds (system-prompt recipe):
@@ -26,7 +26,25 @@ class Chip:
     link_bw: float      # bytes/s per ICI link
 
 
+# Published peaks of one chip, keyed by ``jax.Device.device_kind``.
+# TPU v5e (device_kind "TPU v5 lite"): Google Cloud documentation, "TPU
+# v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of interchip
+# interconnect, i.e. 50 GB/s on each of its four ICI links.
 TPUv5e = Chip(name="tpu_v5e", peak_flops=197e12, hbm_bw=819e9, link_bw=50e9)
+
+PEAKS = {"TPU v5 lite": TPUv5e}
+
+
+def chip_for(device_kind: str) -> Chip:
+    """The peak table entry for ``device_kind``; an unknown kind raises —
+    a roofline against some other chip's peaks is not a measurement."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)} (add the chip to utils/roofline.PEAKS with its "
+            f"source)") from None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,6 +56,7 @@ class RooflineTerms:
     bytes_hbm: float
     bytes_collective: float
     chips: int
+    chip: Chip
     model_flops: float = 0.0
 
     @property
@@ -63,7 +82,7 @@ class RooflineTerms:
     def mfu_bound(self) -> float:
         """Upper bound on achievable MFU at the roofline: the fraction of
         peak the dominant term permits for the *useful* flops."""
-        denom = self.bound_s * self.chips * TPUv5e.peak_flops
+        denom = self.bound_s * self.chips * self.chip.peak_flops
         return self.model_flops / denom if denom else 0.0
 
     def row(self) -> dict:
@@ -83,10 +102,11 @@ def roofline_terms(
     hbm_bytes_per_device: float,
     collective_bytes_per_device: float,
     chips: int,
-    chip: Chip = TPUv5e,
+    chip: Chip,
     model_flops: float = 0.0,
 ) -> RooflineTerms:
-    """All inputs are per-device (SPMD-partitioned) quantities."""
+    """All inputs are per-device (SPMD-partitioned) quantities; ``chip``
+    names the peaks they are held to (:func:`chip_for`)."""
     return RooflineTerms(
         compute_s=flops_per_device / chip.peak_flops,
         memory_s=hbm_bytes_per_device / chip.hbm_bw,
@@ -96,6 +116,7 @@ def roofline_terms(
         bytes_collective=collective_bytes_per_device,
         chips=chips,
         model_flops=model_flops,
+        chip=chip,
     )
 
 

@@ -25,6 +25,15 @@ def _setup(name, rng, n=5, d=64, dtype=jnp.float64):
     return spec, X, G, c
 
 
+def _f32(tree):
+    """float32 copies of a pytree's float arrays: the pallas backend refuses
+    float64 operands, while the jnp reference stays in float64."""
+    return jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.float32)
+        if isinstance(a, jax.Array) and jnp.issubdtype(a.dtype, jnp.floating)
+        else a, tree)
+
+
 # ---------------------------------------------------------------------------
 # Resolution
 # ---------------------------------------------------------------------------
@@ -42,6 +51,28 @@ def test_resolution_order(monkeypatch):
     monkeypatch.delenv("REPRO_BACKEND")
     with pytest.raises(ValueError):
         set_backend("tpu-magic")
+    # an invalid env value is outside input: rejected, never ignored
+    monkeypatch.setenv("REPRO_BACKEND", "tpu-magic")
+    with pytest.raises(ValueError, match="REPRO_BACKEND"):
+        resolve_backend()
+    with use_backend("jnp"):
+        assert resolve_backend() == "jnp"  # an explicit choice still wins
+    monkeypatch.setenv("REPRO_BACKEND", "")
+    assert resolve_backend() in ("jnp", "pallas")  # empty == unset
+
+
+def test_pallas_refuses_float64(rng):
+    """A float64 operand never reaches a Pallas call: the dispatcher names
+    the op and the dtype instead of downcasting or rerouting to jnp."""
+    A = jax.random.normal(jax.random.fold_in(rng, 1), (5, 64), jnp.float64)
+    with use_backend("pallas"):
+        with pytest.raises(TypeError, match="scaled_gram.*float64"):
+            backend.scaled_gram(A, A, 0.5)
+        got = backend.scaled_gram(A.astype(jnp.float32),
+                                  A.astype(jnp.float32), 0.5)
+    assert got.dtype == jnp.float32
+    with use_backend("jnp"):
+        assert backend.scaled_gram(A, A, 0.5).dtype == jnp.float64
 
 
 # ---------------------------------------------------------------------------
@@ -60,7 +91,7 @@ def test_gram_matvec_parity(name, lam_kind, rng):
         f = build_factors(spec, X, lam=lam, c=c, noise=noise)
         want = gram_matvec(f, G, stationary=spec.is_stationary)
     with use_backend("pallas"):
-        got = gram_matvec(f, G, stationary=spec.is_stationary)
+        got = gram_matvec(_f32(f), _f32(G), stationary=spec.is_stationary)
     assert jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)) < 1e-5
 
 
@@ -71,7 +102,7 @@ def test_gram_cg_solve_parity(name, rng):
         f = build_factors(spec, X, lam=0.5, c=c, noise=1e-6)
         want = gram_cg_solve(spec, f, G, tol=1e-6).x
     with use_backend("pallas"):
-        got = gram_cg_solve(spec, f, G, tol=1e-6, maxiter=200).x
+        got = gram_cg_solve(spec, _f32(f), _f32(G), tol=1e-6, maxiter=200).x
     # pallas path accumulates in f32; compare through the operator
     with use_backend("jnp"):
         rw = gram_matvec(f, got, stationary=spec.is_stationary) - G
@@ -86,7 +117,7 @@ def test_woodbury_solve_parity(name, rng):
         f = build_factors(spec, X, lam=0.5, c=c)
         want = woodbury_solve(spec, f, G)
     with use_backend("pallas"):
-        got = woodbury_solve(spec, f, G)
+        got = woodbury_solve(spec, _f32(f), _f32(G))
     assert jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)) < 1e-3
 
 
@@ -162,10 +193,11 @@ def test_backend_vocabulary_parity(rng):
     B = jax.random.normal(jax.random.fold_in(rng, 2), (7, d))
     lam = jnp.abs(jax.random.normal(jax.random.fold_in(rng, 3), (d,))) + 0.1
     spec = get_kernel("rbf")
+    A32, B32 = _f32((A, B))
     with use_backend("pallas"):
-        p_gram = backend.scaled_gram(A, B, lam)
-        p_r = backend.pairwise_r(spec, A, B, lam)
-        p_norms = backend.gram_norms(A, B, lam)
+        p_gram = backend.scaled_gram(A32, B32, lam)
+        p_r = backend.pairwise_r(spec, A32, B32, lam)
+        p_norms = backend.gram_norms(A32, B32, lam)
     with use_backend("jnp"):
         j_gram = backend.scaled_gram(A, B, lam)
         j_r = backend.pairwise_r(spec, A, B, lam)

@@ -186,7 +186,6 @@ def test_solve_single_local_x_stream(rng):
     from jax.sharding import PartitionSpec as P
 
     from repro.core.dist_state import sgpg_extend
-    from repro.core.distributed import _shard_map
     from repro.launch.mesh import make_d_mesh
 
     n, d = 5, 256                 # (cap, cap) psum outputs stay < d_loc
@@ -200,16 +199,19 @@ def test_solve_single_local_x_stream(rng):
     data = st.data
     x, g = _mk(rng, 1, d, seed=47)
 
-    def fn(Xt, x, g):
+    def fn(Xt, x, g, data):
         d2 = data._replace(base=data.base._replace(Xt=Xt))
         out, _ = sgpg_extend(spec, d2, x, g, axis_names=names, noise=1e-6,
                              solve=True)
         return out.base.Z
 
-    sm = _shard_map(fn, mesh=mesh,
-                    in_specs=(P(None, names), P(names), P(names)),
-                    out_specs=P(None, names), check_rep=False)
-    closed = jax.make_jaxpr(sm)(data.base.Xt, x[0], g[0])
+    # the mesh-sharded state rides in as an argument: shard_map refuses
+    # sharded arrays closed over from outside the map
+    sm = jax.shard_map(fn, mesh=mesh,
+                       in_specs=(P(None, names), P(names), P(names),
+                                 st._data_spec()),
+                       out_specs=P(None, names), check_vma=False)
+    closed = jax.make_jaxpr(sm)(data.base.Xt, x[0], g[0], data)
     d_loc = d // mesh.size
     streams = count_data_streams(closed, 0, d_loc)
     assert streams == {"reduction": 1, "expansion": 1}, streams
@@ -294,6 +296,48 @@ def test_sharded_phase_compile_stability():
         _obs.set_enabled(None)
 
 
+def _dot_precisions(jaxpr) -> list:
+    """``precision`` of every dot_general in a jaxpr, sub-jaxprs included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out += _dot_precisions(inner)
+    return out
+
+
+def test_sharded_phases_trace_f32_matmuls_at_highest():
+    """Every matmul of the sharded phase and query programs is traced at
+    HIGHEST precision: a TPU otherwise contracts float32 in one bf16 pass,
+    and the direct solve has no CG step to correct it."""
+    from repro.launch.mesh import make_d_mesh
+
+    st = ShardedGPGState("rbf", 8, window=3, mesh=make_d_mesh(), lam=0.5,
+                         noise=1e-6, dtype=jnp.float32)
+    key = jax.random.PRNGKey(5)
+    for i in range(2):
+        x = jax.random.normal(jax.random.fold_in(key, i), (8,), jnp.float32)
+        st.extend(x, jnp.sin(x))
+    nz = jnp.asarray(st._noise_eff)
+    xp = st._pad_cols(x)
+    programs = {
+        "extend": (st._phase_raw("extend"), (st.data, xp, xp, nz)),
+        "evict": (st._phase_raw("evict"), (st.data, nz)),
+        "refactor": (st._phase_raw("refactor"),
+                     (st.data, jnp.asarray(0.3, jnp.float32), nz)),
+        "query": (st._query_raw(2), (st.data, jnp.stack([xp, xp]))),
+    }
+    highest = (jax.lax.Precision.HIGHEST,) * 2
+    for phase, (fn, args) in programs.items():
+        precisions = _dot_precisions(jax.make_jaxpr(fn)(*args).jaxpr)
+        assert precisions, phase
+        assert all(p == highest for p in precisions), (phase, precisions)
+
+
 def test_psum_bytes_model_sanity():
     assert psum_bytes("extend", cap=6) == 4 * 2 * 2 * 6
     assert psum_bytes("extend", cap=6, with_rhs=True) == 4 * (24 + 36)
@@ -318,7 +362,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.core import GPGState, ShardedGPGState, get_kernel
 from repro.core.dist_state import PHASE_PSUMS, psum_bytes
-from repro.core.distributed import _shard_map, ring_psum
+from repro.core.distributed import ring_psum
 from repro.launch.mesh import make_d_mesh
 from repro.utils.hlo import collective_bytes, count_psums
 
@@ -368,9 +412,9 @@ for kern in ("rbf", "expdot"):
 # shard; the ring all-reduce must equal the cross-device sum, replicated
 x = jnp.arange(8.0 * 3)
 names = tuple(mesh.axis_names)
-ring = _shard_map(lambda v: ring_psum(v, names[0], 8),
-                  mesh=mesh, in_specs=(P(names),), out_specs=P(),
-                  check_rep=False)(x)
+ring = jax.shard_map(lambda v: ring_psum(v, names[0], 8),
+                     mesh=mesh, in_specs=(P(names),), out_specs=P(),
+                     check_vma=False)(x)
 if float(jnp.max(jnp.abs(ring - x.reshape(8, 3).sum(0)))) > 1e-12:
     failures.append(("ring_psum", ring))
 

@@ -22,10 +22,7 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 from functools import partial
 from jax.sharding import PartitionSpec as P
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core import build_factors, get_kernel, gram_matvec, woodbury_solve
 from repro.core.distributed import sharded_gram_matvec, sharded_woodbury_solve
 from repro.runtime import masked_gradient_mean

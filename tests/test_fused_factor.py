@@ -90,8 +90,11 @@ def test_backend_fused_factor_build_parity(name, rng):
     B = jax.random.normal(jax.random.fold_in(rng, 2), (7, d))
     V = jax.random.normal(jax.random.fold_in(rng, 3), (7, d))
     lam = jnp.abs(jax.random.normal(jax.random.fold_in(rng, 4), (d,))) + 0.1
+    # float32 streams on the pallas side (it refuses float64 operands); the
+    # jnp reference stays in float64
+    A32, B32, V32 = (a.astype(jnp.float32) for a in (A, B, V))
     with use_backend("pallas"):
-        p = backend.fused_factor_build(A, B, V, lam, v_scale=lam)
+        p = backend.fused_factor_build(A32, B32, V32, lam, v_scale=lam)
     with use_backend("jnp"):
         j = backend.fused_factor_build(A, B, V, lam, v_scale=lam)
     for gp, gj in zip(p, j):

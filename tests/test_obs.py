@@ -15,6 +15,7 @@ from repro.core.state import GPGState, gpg_extend, gpg_init
 from repro.obs import compile_watch, cost, health, injit
 from repro.obs import trace as obs
 from repro.train.serve import build_gp_serve_step
+from repro.utils import roofline
 from repro.utils.hlo import count_primitive
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
@@ -317,9 +318,15 @@ def test_cost_modeled_and_roofline_fraction():
         c = cost.modeled("t_mm", lambda x, y: x @ y, a, a)
         assert c.flops > 0
         assert obs.gauge_value("cost.t_mm.hbm_bytes") > 0
-        frac = cost.record_measured("t_mm", 1e-3, c)
+        # off a TPU no fraction is published unless the chip is named
+        if jax.devices()[0].platform != "tpu":
+            assert cost.record_measured("t_mm", 1e-3, c) is None
+        frac = cost.record_measured("t_mm", 1e-3, c,
+                                    chip=roofline.chip_for("TPU v5 lite"))
         assert frac is not None and frac > 0
         assert obs.gauge_value("cost.t_mm.roofline_fraction") == frac
+        with pytest.raises(ValueError, match="no published peaks"):
+            roofline.chip_for("cpu")
     with obs.use_obs(False):
         assert cost.modeled("t_mm2", lambda x: x, a) is None
         assert cost.record_measured("t_mm2", 1.0) is None
