@@ -1,0 +1,12 @@
+"""Least time of the window's querys (bench/counts/query.py, per chip) over
+the device busy time inside the benchmark's "query" annotations, every
+device op included, mean over the chips, %."""
+import tracing
+
+
+def read(rec):
+    tr, least = rec["trace"], rec["least_s"].get("query")
+    if not tr or not least:
+        return None
+    busy = sum(b for _, b in tracing.busy_in(tr, "query"))
+    return 100.0 * least / busy if busy > 0 else None
