@@ -732,8 +732,9 @@ class ShardedGPGState:
         return fn
 
     def _query_raw(self, q: int, chunks: Optional[int] = None):
-        """The UNWRAPPED shard_map query program (for ``obs.cost.modeled``
-        — a model lowering must never hit the compile-watched entry)."""
+        """The UNWRAPPED shard_map query program (for lowering and jaxpr
+        inspection — a lowering must never hit the compile-watched
+        entry)."""
         self._query_fn(q, chunks)
         return self._query_raws[(q, chunks)]
 

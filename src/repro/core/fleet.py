@@ -631,28 +631,29 @@ class GPFleet:
 
         if not obs:
             return self
-        for t, (x, g) in obs.items():
-            _guard.check_finite(x, g, what="observation", tenant=t)
-        if not self.window:
-            for t in obs:
-                if self.n(t) >= self.capacity:
-                    raise ValueError(
-                        f"tenant {t!r} is at capacity={self.capacity} "
-                        "(no window configured)")
-        X = np.zeros((self.batch, self.d), dtype=np.asarray(
-            self.fleet.data.X).dtype)
-        G = np.zeros_like(X)
-        for t, (x, g) in obs.items():
-            b = self._slots[t]
-            X[b], G[b] = np.asarray(x), np.asarray(g)
+        with _obs.span("fleet.pack"):
+            for t, (x, g) in obs.items():
+                _guard.check_finite(x, g, what="observation", tenant=t)
+            if not self.window:
+                for t in obs:
+                    if self.n(t) >= self.capacity:
+                        raise ValueError(
+                            f"tenant {t!r} is at capacity={self.capacity} "
+                            "(no window configured)")
+            X = np.zeros((self.batch, self.d), dtype=np.asarray(
+                self.fleet.data.X).dtype)
+            G = np.zeros_like(X)
+            for t, (x, g) in obs.items():
+                b = self._slots[t]
+                X[b], G[b] = np.asarray(x), np.asarray(g)
+            args = (jnp.asarray(X), jnp.asarray(G), self._mask_of(obs))
         with _obs.span("fleet.extend", tenants=len(obs)):
             self.fleet = self._launch(
                 "extend", lambda fl, X_, G_, op: fleet_extend(
                     self.spec, fl, X_, G_, op, window=self.window,
                     jitter=self.jitter, deg_thresh=self.deg_thresh,
                     tol=self.tol, maxiter=self.maxiter),
-                self.fleet, jnp.asarray(X), jnp.asarray(G),
-                self._mask_of(obs))
+                self.fleet, *args)
             if _obs.enabled():
                 _obs.REGISTRY.inc("fleet.extend_calls", len(obs))
         for t in obs:
@@ -683,17 +684,19 @@ class GPFleet:
 
         if not rhs:
             return self
-        R = np.zeros((self.batch, self.capacity, self.d), dtype=np.asarray(
-            self.fleet.data.X).dtype)
-        for t, r in rhs.items():
-            r = np.atleast_2d(np.asarray(r))
-            R[self._slots[t], : r.shape[0]] = r
+        with _obs.span("fleet.pack"):
+            R = np.zeros((self.batch, self.capacity, self.d),
+                         dtype=np.asarray(self.fleet.data.X).dtype)
+            for t, r in rhs.items():
+                r = np.atleast_2d(np.asarray(r))
+                R[self._slots[t], : r.shape[0]] = r
+            args = (jnp.asarray(R), self._mask_of(rhs))
         with _obs.span("fleet.resolve", tenants=len(rhs)):
             self.fleet = self._launch(
                 "resolve", lambda fl, R_, op: fleet_resolve(
                     self.spec, fl, R_, op, tol=self.tol,
                     maxiter=self.maxiter),
-                self.fleet, jnp.asarray(R), self._mask_of(rhs))
+                self.fleet, *args)
         for t in rhs:
             self._bump(self._slots[t], factors=False)
         return self
@@ -725,30 +728,35 @@ class GPFleet:
 
         if not queries:
             return {}
-        qs = {t: np.atleast_2d(np.asarray(x)) for t, x in queries.items()}
-        qmax = max(x.shape[0] for x in qs.values())
-        Q = int(q_pad) if q_pad else 1 << (qmax - 1).bit_length()
-        if qmax > Q:
-            raise ValueError(f"request of {qmax} queries exceeds "
-                             f"q_pad={Q}")
-        Xq = np.zeros((self.batch, Q, self.d), dtype=np.asarray(
-            self.fleet.data.X).dtype)
-        for t, x in qs.items():
-            Xq[self._slots[t], : x.shape[0]] = x
+        with _obs.span("fleet.pack"):
+            qs = {t: np.atleast_2d(np.asarray(x))
+                  for t, x in queries.items()}
+            qmax = max(x.shape[0] for x in qs.values())
+            Q = int(q_pad) if q_pad else 1 << (qmax - 1).bit_length()
+            if qmax > Q:
+                raise ValueError(f"request of {qmax} queries exceeds "
+                                 f"q_pad={Q}")
+            Xq = np.zeros((self.batch, Q, self.d), dtype=np.asarray(
+                self.fleet.data.X).dtype)
+            for t, x in qs.items():
+                Xq[self._slots[t], : x.shape[0]] = x
+            Xq = jnp.asarray(Xq)
         with _obs.span("fleet.query", tenants=len(qs), q=Q):
             out = self._launch(
                 "posterior", lambda fl, Xq_: fleet_posterior(
                     self.spec, fl, Xq_),
-                self.fleet, jnp.asarray(Xq))
+                self.fleet, Xq)
             if _obs.enabled():
                 _obs.REGISTRY.inc("fleet.query_calls", len(qs))
                 _obs.REGISTRY.inc("fleet.query_points",
                                   sum(x.shape[0] for x in qs.values()))
-        return {
-            t: PosteriorBatch(value=out.value[self._slots[t], : x.shape[0]],
-                              grad=out.grad[self._slots[t], : x.shape[0]])
-            for t, x in qs.items()
-        }
+        with _obs.span("fleet.unpack"):
+            return {
+                t: PosteriorBatch(
+                    value=out.value[self._slots[t], : x.shape[0]],
+                    grad=out.grad[self._slots[t], : x.shape[0]])
+                for t, x in qs.items()
+            }
 
     def mll(self, tenants=None) -> dict:
         """Per-tenant exact MLL at current hypers (one vmapped launch)."""

@@ -733,7 +733,8 @@ class GPGState:
         # admission guardrail: a NaN/inf observation raises a typed error
         # HERE, before any factor strip sees it (host-side: the jitted
         # extend program is byte-identical with guardrails on or off)
-        _guard.check_finite(x, g, what="observation")
+        with _obs.span("guard.check_finite"):
+            _guard.check_finite(x, g, what="observation")
         with _obs.span("state.extend"):
             # the in-jit tap counts degenerate pivots as they happen; the
             # host-side counter below is the device-synced ground truth
@@ -752,8 +753,10 @@ class GPGState:
                     # iterative regime from here on
                     self.window = None
                 else:
-                    self.data = gpg_evict(self.spec, self.data,
-                                          noise=self._noise_eff, solve=False)
+                    with _obs.span("state.evict"):
+                        self.data = gpg_evict(self.spec, self.data,
+                                              noise=self._noise_eff,
+                                              solve=False)
                     if self._raw_X is not None:
                         self._raw_X.pop(0)
                         self._raw_G.pop(0)
@@ -786,7 +789,8 @@ class GPGState:
         # (repro.resilience.guardrails) — triggers on NON-finite only,
         # so healthy-trajectory bits are untouched
         if _guard.enabled():
-            _guard.after_mutation(self)
+            with _obs.span("guard.after_mutation"):
+                _guard.after_mutation(self)
         return self
 
     def evict(self, k: int = 1) -> "GPGState":

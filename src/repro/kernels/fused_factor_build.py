@@ -94,6 +94,7 @@ def fused_factor_build_padded(
     grid = (d // block_d,)
     return pl.pallas_call(
         _kernel,
+        name="fused_factor_build",
         grid=grid,
         in_specs=[
             block((na_, block_d), lambda i: (0, i)),

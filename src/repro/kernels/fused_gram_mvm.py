@@ -125,6 +125,7 @@ def fused_gram_mvm_padded(
     grid = (2, d // block_d)
     return pl.pallas_call(
         functools.partial(_kernel, stationary=stationary, noise=float(noise)),
+        name="fused_gram_mvm",
         grid=grid,
         in_specs=[
             block((n, n), lambda p, j: (0, 0)),
@@ -201,6 +202,7 @@ def fused_gram_mvm_multi_padded(
     return pl.pallas_call(
         functools.partial(_kernel_multi, stationary=stationary,
                           noise=float(noise)),
+        name="fused_gram_mvm_multi",
         grid=grid,
         in_specs=[
             block((n, n), lambda p, j: (0, 0)),

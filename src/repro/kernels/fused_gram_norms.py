@@ -51,6 +51,7 @@ def fused_gram_norms_padded(
     grid = (d // block_d,)
     return pl.pallas_call(
         _kernel,
+        name="fused_gram_norms",
         grid=grid,
         in_specs=[
             block((na_, block_d), lambda i: (0, i)),

@@ -76,6 +76,7 @@ def small_matmul_padded(
     grid = (d // block_d,)
     return pl.pallas_call(
         _small_matmul_kernel,
+        name="small_matmul",
         grid=grid,
         in_specs=[
             block((nq, n), lambda i: (0, 0)),
@@ -108,6 +109,7 @@ def gram_update_padded(
     grid = (d // block_d,)
     return pl.pallas_call(
         functools.partial(_kernel, noise=float(noise)),
+        name="gram_update",
         grid=grid,
         in_specs=[
             block((nq, n), lambda i: (0, 0)),

@@ -52,6 +52,7 @@ def skinny_gram_padded(
     grid = (d // block_d,)
     return pl.pallas_call(
         _kernel,
+        name="skinny_gram",
         grid=grid,
         in_specs=[
             block((na, block_d), lambda i: (0, i)),

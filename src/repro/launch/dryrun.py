@@ -112,7 +112,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str) -> dict:
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis()
             hlo = compiled.as_text()
-        # trip-count-aware structural cost model (utils/hlo_cost.py) —
+        # trip-count-aware structural cost model (repro.utils.hlo_cost) —
         # compiled.cost_analysis() counts while bodies once, which under-
         # reports scanned-layer models by ~n_layers x.
         costs = analyze_hlo(hlo)

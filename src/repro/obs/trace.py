@@ -1,16 +1,25 @@
-"""Host-side spans, the metric registry, and the JSONL event sink.
+"""Host-side spans, the compile counter, the metric registry, and the
+JSONL event sink.
 
 The observability substrate for the whole GP inference stack
-(DESIGN.md sec. 13, docs/observability.md).  Three pieces:
+(DESIGN.md sec. 13, docs/observability.md).  Four pieces:
 
-  * ``span(name)``  — nestable context managers with monotonic timing.
-    Nesting builds a dotted path (``span("hmc.phase2")`` inside
-    ``span("hmc.gpg_hmc")`` records ``hmc.gpg_hmc.hmc.phase2``); every
-    completed span observes a ``span.<path>.seconds`` histogram and, when
-    a sink is configured, appends one JSONL event.  With
-    ``REPRO_OBS_PROFILER=on`` each span additionally opens a
-    ``jax.profiler.TraceAnnotation`` so the same names show up inside
-    Perfetto/TensorBoard device traces.
+  * ``span(name)``  — nestable context managers.  Every span opens a
+    ``jax.profiler.TraceAnnotation(name)`` whenever a profiler session
+    is active, whatever the master switch says, so the program's host
+    time lands on the profiler's clock beside the device ops; the
+    profiler nests spans by time.  It never syncs the device or
+    compiles anything.  With observability on, a completed span also
+    observes a ``span.<path>.seconds`` histogram (nesting builds a
+    dotted path: ``span("hmc.phase2")`` inside ``span("hmc.gpg_hmc")``
+    records ``hmc.gpg_hmc.hmc.phase2``) and, when a sink is configured,
+    appends one JSONL event.  While a profiler session is active the
+    completed spans are also kept in memory (:func:`profiled`).
+  * the compile counter — one ``jax.monitoring`` listener on XLA's
+    backend-compile event adds each executable built (compiled, or
+    loaded from the persistent cache) and its seconds to the innermost
+    open span (``"none"`` outside any): :func:`compiles_by_span`.  With
+    observability on it also feeds ``compile.span.<name>.{count,seconds}``.
   * ``Registry``    — a process-global store of counters (monotonic),
     gauges (last value) and histograms (count/total/min/max).  Cheap
     enough to be always-on internally; the *wiring call sites* across
@@ -23,20 +32,26 @@ The observability substrate for the whole GP inference stack
     run from the log alone.
 
 The master switch is the ``REPRO_OBS`` env var (default OFF) or
-``set_enabled``/``use_obs``.  Everything here is host-side python — when
-disabled, nothing in this module touches a jaxpr, and the in-jit taps
-(``repro.obs.injit``) are trace-time no-ops, so compiled programs are
+``set_enabled``/``use_obs``; it gates the registry and the sink, not the
+profiler annotations.  Everything here is host-side python — nothing in
+this module touches a jaxpr, and the in-jit taps (``repro.obs.injit``)
+are trace-time no-ops when disabled, so compiled programs are
 bit-identical with observability off (asserted in tests/test_obs.py).
 """
 from __future__ import annotations
 
 import atexit
+import collections
 import contextlib
 import json
 import os
 import threading
 import time
 from typing import Any, Iterator, Optional
+
+import jax
+from jax import monitoring as _monitoring
+from jax._src import profiler as _jax_profiler
 
 _ON_VALUES = ("1", "on", "true", "yes")
 
@@ -49,11 +64,28 @@ _TLS = threading.local()
 # The master switch
 # ---------------------------------------------------------------------------
 
+_ENCODED: dict = {}
+
+
+def _env(key: str) -> str:
+    """``os.environ.get(key, "")`` without the KeyError that makes a
+    missing key cost ~2 us: every span and call site reads the switch."""
+    env = os.environ
+    data = getattr(env, "_data", None)
+    if data is None:
+        return env.get(key, "")
+    enc = _ENCODED.get(key)
+    if enc is None:
+        enc = _ENCODED[key] = env.encodekey(key)
+    raw = data.get(enc)
+    return "" if raw is None else env.decodevalue(raw)
+
+
 def enabled() -> bool:
     """Whether observability is on: ``set_enabled`` override > REPRO_OBS."""
     if _FORCED is not None:
         return _FORCED
-    return os.environ.get("REPRO_OBS", "").strip().lower() in _ON_VALUES
+    return _env("REPRO_OBS").strip().lower() in _ON_VALUES
 
 
 def set_enabled(on: Optional[bool]) -> None:
@@ -71,11 +103,6 @@ def use_obs(on: bool = True) -> Iterator[None]:
         yield
     finally:
         set_enabled(prev)
-
-
-def _profiler_on() -> bool:
-    return os.environ.get("REPRO_OBS_PROFILER", "").strip().lower() \
-        in _ON_VALUES
 
 
 # ---------------------------------------------------------------------------
@@ -254,37 +281,134 @@ def _final_flush() -> None:
 # Spans
 # ---------------------------------------------------------------------------
 
-@contextlib.contextmanager
-def span(name: str, **attrs: Any) -> Iterator[Optional[str]]:
-    """A timed, nestable span.  Disabled mode: a bare nullcontext-grade
-    no-op (one ``enabled()`` predicate).  Enabled: monotonic duration into
-    the ``span.<path>.seconds`` histogram + one JSONL event, and a
-    ``jax.profiler.TraceAnnotation`` when ``REPRO_OBS_PROFILER=on`` so
-    the span lands in Perfetto/TensorBoard device traces."""
-    if not enabled():
-        yield None
-        return
+_ANNOTATION = jax.profiler.TraceAnnotation
+# spans and compiles of the newest profiler session, bounded
+_PROFILED_MAX = 1 << 17
+_PROFILED_SPANS: collections.deque = collections.deque(maxlen=_PROFILED_MAX)
+_PROFILED_COMPILES: collections.deque = collections.deque(
+    maxlen=_PROFILED_MAX)
+_SESSION = {"on": False, "session": None}
+
+
+def _stack() -> list:
     stack = getattr(_TLS, "stack", None)
     if stack is None:
         stack = _TLS.stack = []
-    path = ".".join(stack + [name])
-    stack.append(name)
-    wall = time.time()
-    t0 = time.monotonic()
-    prof = contextlib.nullcontext()
-    if _profiler_on():
-        import jax
+    return stack
 
-        prof = jax.profiler.TraceAnnotation(path)
-    try:
-        with prof:
-            yield path
-    finally:
-        stack.pop()
-        dur = time.monotonic() - t0
+
+def _profiling() -> bool:
+    """Whether a profiler session records host annotations now; a new
+    session (``jax.profiler.start_trace``'s, where JAX shows it) empties
+    what :func:`profiled` keeps."""
+    on = _ANNOTATION.is_enabled()
+    if on:
+        state = getattr(_jax_profiler, "_profile_state", None)
+        session = getattr(state, "profile_session", None)
+        if not _SESSION["on"] or session is not _SESSION["session"]:
+            with _LOCK:
+                _SESSION["session"] = session
+                _PROFILED_SPANS.clear()
+                _PROFILED_COMPILES.clear()
+    _SESSION["on"] = on
+    return on
+
+
+class span:
+    """A nestable span: ``with span("state.extend"): ...``.
+
+    Opens ``jax.profiler.TraceAnnotation(name)`` while a profiler session
+    is active (the annotation records nothing otherwise, so it is not
+    made), and keeps the thread's span stack, which the compile counter
+    reads.  With observability on (:func:`enabled`), the monotonic
+    duration goes into the ``span.<path>.seconds`` histogram plus one
+    JSONL event.  Disabled and unprofiled, a span costs one to two
+    microseconds.  ``as`` binds the dotted path when observability is on,
+    else None."""
+
+    __slots__ = ("name", "attrs", "_ann", "_t0", "_wall", "_path", "_ns")
+
+    def __init__(self, name: str, **attrs: Any):
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self) -> Optional[str]:
+        stack = _stack()
+        self._path = ".".join(stack + [self.name]) if enabled() else None
+        stack.append(self.name)
+        self._ann = None
+        if _profiling():
+            self._ns = (time.time_ns(), len(stack) - 1)
+            self._ann = _ANNOTATION(self.name)
+            self._ann.__enter__()
+        if self._path is not None:
+            self._wall = time.time()
+            self._t0 = time.monotonic()
+        return self._path
+
+    def __exit__(self, *exc) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            start, depth = self._ns
+            _PROFILED_SPANS.append((self.name, start, time.time_ns(), depth))
+        _stack().pop()
+        path = self._path
+        if path is None:
+            return
+        dur = time.monotonic() - self._t0
         REGISTRY.observe(f"span.{path}.seconds", dur)
-        ev = {"type": "span", "name": name, "path": path, "t": wall,
-              "dur_s": dur}
-        if attrs:
-            ev["attrs"] = attrs
+        ev = {"type": "span", "name": self.name, "path": path,
+              "t": self._wall, "dur_s": dur}
+        if self.attrs:
+            ev["attrs"] = self.attrs
         emit(ev)
+
+
+def profiled() -> dict:
+    """The completed spans and the compiles of the newest profiler
+    session, on the host's wall clock (``time.time_ns``), which the
+    profiler's host annotations share up to its session's origin:
+
+        {"spans": [(name, start_ns, end_ns, depth), ...],
+         "compiles": [(innermost span, end_ns, seconds), ...]}
+    """
+    with _LOCK:
+        return {"spans": list(_PROFILED_SPANS),
+                "compiles": list(_PROFILED_COMPILES)}
+
+
+# ---------------------------------------------------------------------------
+# The compile counter
+# ---------------------------------------------------------------------------
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILES: dict[str, list] = {}
+
+
+def _on_compile(event: str, duration: float, **_: Any) -> None:
+    """Runs once per executable built, in the thread that asked for it."""
+    if event != COMPILE_EVENT:
+        return
+    stack = getattr(_TLS, "stack", None)
+    name = stack[-1] if stack else "none"
+    with _LOCK:
+        tot = _COMPILES.setdefault(name, [0, 0.0])
+        tot[0] += 1
+        tot[1] += float(duration)
+        if _profiling():
+            _PROFILED_COMPILES.append((name, time.time_ns(), float(duration)))
+    if enabled():
+        REGISTRY.inc(f"compile.span.{name}.count")
+        REGISTRY.inc(f"compile.span.{name}.seconds", duration)
+
+
+def compiles_by_span() -> dict:
+    """``{span name: {"count": n, "seconds": s}}``: every executable the
+    process built since it started, by the innermost span open at the
+    time (``"none"`` outside any)."""
+    with _LOCK:
+        return {k: {"count": n, "seconds": sec}
+                for k, (n, sec) in _COMPILES.items()}
+
+
+_monitoring.register_event_duration_secs_listener(_on_compile)

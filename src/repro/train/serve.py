@@ -22,7 +22,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.models import (SHAPES, ModelConfig, batch_specs, build_model,
                           set_activation_rules)
 from repro.obs import compile_watch as _cw
-from repro.obs import cost as _cost
 from repro.obs import trace as _obs
 from repro.resilience import guardrails as _guard
 from repro.resilience.errors import (DeadlineExceededError,
@@ -145,9 +144,9 @@ class GPServeBundle:
     probe: Optional[jnp.ndarray]
     return_std: bool = False
     return_grad_std: bool = False
-    step_fn: Optional[Callable] = None   # the raw (unjitted) step — the
-    # cost model lowers THIS through a fresh jit, never through the
-    # compile-watched entry point (a model lowering is not a serve compile)
+    step_fn: Optional[Callable] = None   # the raw (unjitted) step, for
+    # lowering and inspection through a fresh jit, never through the
+    # compile-watched entry point (a lowering is not a serve compile)
     _solver_cache: Any = None        # OrderedDict: revision key -> GramSolver
     # LU factorizations per cached revision are O(cap^4) floats — a
     # long-running server interleaving refit()/extend() with queries would
@@ -220,7 +219,6 @@ class GPServeBundle:
                 return_std=self.return_std, return_grad_std=False)
 
     def _query(self, Xq, PosteriorBatch):
-        obs_on = _obs.enabled()
         Xq = jnp.atleast_2d(Xq)
         q, d = Xq.shape
         b = self.microbatch
@@ -247,35 +245,16 @@ class GPServeBundle:
             f = f._replace(c=None)
         Xp = jnp.pad(Xq.astype(f.Xt.dtype), ((0, pad), (0, 0)))
         solver = self.refresh_solver() if want_std else None
-        n_chunks = (q + pad) // b
-        costs = None
-        if obs_on:
+        if _obs.enabled():
             _obs.REGISTRY.inc("serve.requests")
             _obs.REGISTRY.inc("serve.points", q)
-            _obs.REGISTRY.set_gauge("serve.queue_depth", n_chunks)
-            if self.step_fn is not None:
-                # modeled bytes/flops of ONE chunk, scaled to the request;
-                # cached per signature so steady-state requests pay nothing
-                first = (f, Z) + ((solver,) if want_std else ()) \
-                    + (Xp[0:b],)
-                if self.probe is not None:
-                    first = first + (self.probe,)
-                costs = _cost.modeled("gp_serve_step", self.step_fn,
-                                      *first, scale=float(n_chunks))
-        import time as _time
-
-        t0 = _time.monotonic()
+            _obs.REGISTRY.set_gauge("serve.queue_depth", (q + pad) // b)
         chunks = []
         for i in range(0, q + pad, b):
             args = (f, Z) + ((solver,) if want_std else ()) + (Xp[i:i + b],)
             if self.probe is not None:
                 args = args + (self.probe,)
             chunks.append(self.step(*args))
-        if obs_on:
-            jax.block_until_ready(chunks)
-            dt = _time.monotonic() - t0
-            _obs.REGISTRY.observe("serve.request_seconds", dt)
-            _cost.record_measured("gp_serve_step", dt, costs)
         cat = lambda xs: jnp.concatenate(xs)[:q]
         out = PosteriorBatch(
             value=cat([c.value for c in chunks]),
@@ -591,14 +570,13 @@ class GPFleetServer:
         from repro.runtime.recovery import SimulatedFailure
 
         cfg = self.config
-        self.steps += 1
-        completed = self._sweep_deadlines()
-        batch = self._take_head_of_line()
-        with _obs.span("fleet.serve.step", requests=len(batch),
-                       queued=len(self._queue)):
-            by_op: dict = {}
-            for r in batch:
-                by_op.setdefault(r.op, []).append(r)
+        with _obs.span("fleet.serve.step", queued=len(self._queue)):
+            self.steps += 1
+            completed = self._sweep_deadlines()
+            with _obs.span("fleet.pack"):
+                by_op: dict = {}
+                for r in self._take_head_of_line():
+                    by_op.setdefault(r.op, []).append(r)
             # lifecycle before queries: a step's queries see that step's
             # extends only for OTHER tenants (self ops are serialized by
             # head-of-line), so order here is launch-count, not semantics
@@ -725,14 +703,16 @@ class GPFleetServer:
                             else (r.payload, False))
             (std_reqs if want_std else mean_reqs).append((r, xq))
         if mean_reqs:
-            qmax = max(jnp.atleast_2d(jnp.asarray(x)).shape[0]
-                       for _, x in mean_reqs)
-            bucket = max(self.config.q_bucket,
-                         1 << (max(qmax, 1) - 1).bit_length())
+            with _obs.span("fleet.pack"):
+                qmax = max(jnp.atleast_2d(jnp.asarray(x)).shape[0]
+                           for _, x in mean_reqs)
+                bucket = max(self.config.q_bucket,
+                             1 << (max(qmax, 1) - 1).bit_length())
             out = self.fleet.posterior(
                 {r.tenant: x for r, x in mean_reqs}, q_pad=bucket)
-            for r, _ in mean_reqs:
-                r.result = out[r.tenant]
+            with _obs.span("fleet.unpack"):
+                for r, _ in mean_reqs:
+                    r.result = out[r.tenant]
         for r, xq in std_reqs:
             r.result = self.query_std(r.tenant, xq)
 
@@ -809,7 +789,6 @@ class ShardedGPServeBundle:
     def query(self, Xq):
         from repro.core.query import PosteriorBatch
 
-        obs_on = _obs.enabled()
         st = self.state
         Xq = jnp.atleast_2d(Xq)
         q = Xq.shape[0]
@@ -817,29 +796,13 @@ class ShardedGPServeBundle:
         pad = (-q) % b
         Xp = jnp.pad(jnp.asarray(Xq, jnp.asarray(st.data.base.X).dtype),
                      ((0, pad), (0, 0)))
-        n_chunks = (q + pad) // b
         with _obs.span("serve.query.sharded", q=q, shards=st.ndev):
-            costs = None
-            if obs_on:
+            if _obs.enabled():
                 _obs.REGISTRY.inc("serve.requests")
                 _obs.REGISTRY.inc("serve.points", q)
-                _obs.REGISTRY.set_gauge("serve.queue_depth", n_chunks)
-                # roofline entry for the sharded serve step: model ONE
-                # microbatch through a fresh jit of the raw shard_map
-                # program, scaled to the request
-                costs = _cost.modeled(
-                    "gp_serve_step_sharded", st._query_raw(b, self.chunks),
-                    st.data, st._pad_cols(Xp[0:b]), scale=float(n_chunks))
-            import time as _time
-
-            t0 = _time.monotonic()
+                _obs.REGISTRY.set_gauge("serve.queue_depth", (q + pad) // b)
             outs = [st.posterior(Xp[i:i + b], chunks=self.chunks)
                     for i in range(0, q + pad, b)]
-            if obs_on:
-                jax.block_until_ready([o.value for o in outs])
-                dt = _time.monotonic() - t0
-                _obs.REGISTRY.observe("serve.request_seconds", dt)
-                _cost.record_measured("gp_serve_step_sharded", dt, costs)
         return PosteriorBatch(
             value=jnp.concatenate([o.value for o in outs])[:q],
             grad=jnp.concatenate([o.grad for o in outs])[:q],

@@ -1,7 +1,8 @@
-"""repro.obs: registry/span/sink semantics, the zero-cost disabled-mode
-guarantee (jaxpr/HLO), in-jit taps, the recompile sentinel, the serve
-LRU revision keying, health/cost probes, the --check null handling, and
-the check_telemetry gate."""
+"""repro.obs: registry/span/sink semantics, spans on the profiler's clock
+and the compile counter, the zero-cost disabled-mode guarantee
+(jaxpr/HLO), in-jit taps, the recompile sentinel, the serve LRU revision
+keying, health probes, the --check null handling, and the check_telemetry
+gate."""
 import json
 import pathlib
 import sys
@@ -12,10 +13,9 @@ import pytest
 
 from repro.core.kernels import get_kernel
 from repro.core.state import GPGState, gpg_extend, gpg_init
-from repro.obs import compile_watch, cost, health, injit
+from repro.obs import compile_watch, health, injit
 from repro.obs import trace as obs
 from repro.train.serve import build_gp_serve_step
-from repro.utils import roofline
 from repro.utils.hlo import count_primitive
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
@@ -26,7 +26,6 @@ def _clean_registry():
     obs.reset()
     obs.configure(None)
     compile_watch._WATCHES.clear()
-    cost.clear_model_cache()
     yield
     obs.reset()
     obs.configure(None)
@@ -100,6 +99,115 @@ def test_enabled_resolution_env(monkeypatch):
     obs.set_enabled(True)
     assert obs.enabled()        # forced override beats the env
     obs.set_enabled(None)
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock + the compile counter
+# ---------------------------------------------------------------------------
+
+def _host_events(logdir):
+    from jax.profiler import ProfileData
+
+    found = list(pathlib.Path(logdir).rglob("*.xplane.pb"))
+    assert len(found) == 1
+    return [(e.name, int(e.start_ns), int(e.duration_ns))
+            for plane in ProfileData.from_file(str(found[0])).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_span_lands_in_profiler_trace_with_obs_off(tmp_path):
+    import time
+
+    with obs.use_obs(False):
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs.span("state.probe", k=1):
+                with obs.span("guard.probe"):
+                    time.sleep(0.002)
+        finally:
+            jax.profiler.stop_trace()
+    ev = {n: (s, d) for n, s, d in _host_events(tmp_path)}
+    # each span annotates under its leaf name; the profiler nests by time
+    (o_s, o_d), (i_s, i_d) = ev["state.probe"], ev["guard.probe"]
+    assert i_d >= 2_000_000
+    assert o_s <= i_s and i_s + i_d <= o_s + o_d
+    # the registry stays behind REPRO_OBS
+    assert obs.REGISTRY.hists == {}
+    # the spans kept for the session: same durations, same nesting, on
+    # the wall clock the profiler's host annotations share
+    kept = {n: (s, e, d) for n, s, e, d in obs.profiled()["spans"]}
+    assert kept["guard.probe"][2] == 1 and kept["state.probe"][2] == 0
+    for name, (s, d) in ev.items():
+        if name in kept:
+            assert abs((kept[name][1] - kept[name][0]) - d) < 1_000_000
+    off = {kept[n][0] - ev[n][0] for n in kept}
+    assert max(off) - min(off) < 1_000_000
+
+
+def test_profiled_keeps_the_newest_session(tmp_path):
+    for i in range(2):
+        jax.profiler.start_trace(str(tmp_path / str(i)))
+        try:
+            with obs.span(f"state.session{i}"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+    assert [n for n, *_ in obs.profiled()["spans"]] == ["state.session1"]
+    with obs.span("state.unprofiled"):
+        pass
+    assert [n for n, *_ in obs.profiled()["spans"]] == ["state.session1"]
+
+
+def test_compile_attributed_to_innermost_span():
+    before = obs.compiles_by_span()
+    x = jnp.arange(5.0)
+    with obs.use_obs(True):
+        with obs.span("outer.cc"):
+            with obs.span("inner.cc"):
+                # a function never seen before: one backend compile
+                jax.jit(lambda v: v * 3.25 + 0.5)(x).block_until_ready()
+            jax.jit(lambda v: v - 1.75)(x).block_until_ready()
+    after = obs.compiles_by_span()
+    assert after["inner.cc"]["count"] - \
+        before.get("inner.cc", {}).get("count", 0) == 1
+    assert after["inner.cc"]["seconds"] > 0
+    assert after["outer.cc"]["count"] - \
+        before.get("outer.cc", {}).get("count", 0) == 1
+    assert obs.counter_value("compile.span.inner.cc.count") == 1
+    assert obs.counter_value("compile.span.inner.cc.seconds") > 0
+    # outside any span the compile counts under "none"
+    n0 = obs.compiles_by_span().get("none", {}).get("count", 0)
+    jax.jit(lambda v: v * 0.125)(x).block_until_ready()
+    assert obs.compiles_by_span()["none"]["count"] == n0 + 1
+
+
+def test_serve_query_never_syncs_and_keeps_its_jaxpr(monkeypatch):
+    synced = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        synced.append(1)
+        return real(x)
+
+    st = _mk_state(d=4, n=3)
+    Xq = jnp.ones((3, 4))
+    jaxprs = {}
+    for on in (False, True):
+        with obs.use_obs(on):
+            serve = build_gp_serve_step(st, microbatch=2)
+            monkeypatch.setattr(jax, "block_until_ready", counting)
+            out = serve.query(Xq)
+            monkeypatch.setattr(jax, "block_until_ready", real)
+            jaxprs[on] = str(
+                jax.make_jaxpr(lambda q: serve.query(q).value)(Xq))
+    assert synced == []
+    assert out.value.shape == (3,)
+    # the query program is the same with telemetry on and off; on, the
+    # compile watcher's shim wraps the same step function
+    same = {on: " ".join(j.replace("shimmed", "fn").split())
+            for on, j in jaxprs.items()}
+    assert same[False] == same[True]
 
 
 # ---------------------------------------------------------------------------
@@ -312,26 +420,6 @@ def test_health_probes_and_monitor():
         assert obs.gauge_value("health.cond_k1n") >= 1.0
 
 
-def test_cost_modeled_and_roofline_fraction():
-    with obs.use_obs(True):
-        a = jnp.ones((8, 8), jnp.float32)
-        c = cost.modeled("t_mm", lambda x, y: x @ y, a, a)
-        assert c.flops > 0
-        assert obs.gauge_value("cost.t_mm.hbm_bytes") > 0
-        # off a TPU no fraction is published unless the chip is named
-        if jax.devices()[0].platform != "tpu":
-            assert cost.record_measured("t_mm", 1e-3, c) is None
-        frac = cost.record_measured("t_mm", 1e-3, c,
-                                    chip=roofline.chip_for("TPU v5 lite"))
-        assert frac is not None and frac > 0
-        assert obs.gauge_value("cost.t_mm.roofline_fraction") == frac
-        with pytest.raises(ValueError, match="no published peaks"):
-            roofline.chip_for("cpu")
-    with obs.use_obs(False):
-        assert cost.modeled("t_mm2", lambda x: x, a) is None
-        assert cost.record_measured("t_mm2", 1.0) is None
-
-
 # ---------------------------------------------------------------------------
 # benchmarks/run.py --check: null/absent metrics + telemetry skip
 # ---------------------------------------------------------------------------
@@ -402,7 +490,6 @@ def test_check_telemetry_flags_violations(tmp_path):
     assert "recompile-sentinel violation" in text
     assert "malformed JSON" in text
     assert "state.refactor_fallback" in text  # missing counter
-    assert "cost." in text                    # no modeled gauges
     assert "counter/span mismatch" in text    # 5 claimed vs 1 span event
     # --allow-recompile downgrades exactly the sentinel failure
     relaxed = check(str(log), allow_recompile=True)

@@ -10,7 +10,7 @@ The drill exercises, in one process (one telemetry log):
 
   happy path          a ``GPServeBundle`` extend/query workload — the
                       required core counters/spans (``state.extend``,
-                      ``serve.query``, ``cost.*``) come from here, so the
+                      ``serve.query``) come from here, so the
                       gate proves chaos rode on a REAL serving stack;
   nan_payload         corrupted observations rejected at admission with a
                       typed error (server path);
