@@ -3,10 +3,11 @@ sec. 17).
 
   errors.py      typed failure taxonomy (admission rejects, degraded
                  queries, shed/deadline/retry/quarantine, corruption)
-  guardrails.py  host-side numerical guardrails: non-finite admission,
-                 the jitter-escalation ladder, the CG-divergence
-                 watchdog predicate, the bf16-drift trip-wire — zero
-                 jaxpr cost by construction
+  guardrails.py  numerical guardrails outside the guarded programs:
+                 non-finite admission (on the device for device
+                 arrays), the jitter-escalation ladder, the
+                 CG-divergence watchdog predicate, the bf16-drift
+                 trip-wire — zero jaxpr cost by construction
   snapshot.py    snapshot/restore of all three state flavors through the
                  two-phase CheckpointManager (elastic fleet repack,
                  cross-mesh sharded restore, corruption fallback)

@@ -1,12 +1,15 @@
 """Numerical guardrails: admission checks, the jitter-escalation ladder,
 the CG-divergence watchdog, and the bf16-drift trip-wire.
 
-Everything in this module runs on the HOST, outside jit — the guarded
-jitted programs are byte-for-byte the same jaxprs with guardrails on or
-off (the zero-cost contract, asserted by ``bench_resilience`` with the
-same primitive-count technique as ``obs/injit.py``).  The only cost the
-happy path pays is a handful of scalar device reads per mutation, and
-only while guardrails are enabled.
+Every guardrail runs outside the guarded programs, driven from the host:
+the guarded jitted programs are byte-for-byte the same jaxprs with
+guardrails on or off (the zero-cost contract, asserted by
+``bench_resilience`` with the same primitive-count technique as
+``obs/injit.py``).  The admission check tests device-resident payloads
+where they live, with one small program of its own and one bool read
+back; host payloads are tested on the host.  Beyond that, the happy path
+pays a handful of scalar device reads per mutation, and only while
+guardrails are enabled.
 
 Master switch: ``REPRO_GUARDRAILS`` env var ("1"/"on"/"true"/"yes"; the
 default is ON — resilience is the point), overridable in-process with
@@ -35,6 +38,8 @@ import os
 from contextlib import contextmanager
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import trace as _trace
@@ -86,28 +91,53 @@ def record_recovery(kind: str, **attrs) -> None:
 # ---------------------------------------------------------------------------
 
 
+@jax.jit
+def _all_finite(arrays):
+    """Whether every element of every array is finite: one program over
+    all of them, built once per tuple of shapes and dtypes."""
+    return jnp.stack([jnp.isfinite(a).all() for a in arrays]).all()
+
+
 def check_finite(*arrays, what: str = "observation",
                  tenant=None) -> None:
     """Reject non-finite payloads with a typed error BEFORE any factor op.
 
-    Host-side by construction: the admission read happens on the request
-    payload (usually already a numpy array), never inside a traced
-    program, so the serve jaxprs are untouched.
+    Device-resident payloads (``jax.Array``s) are tested where they live,
+    by one fused reduction over all of them, and one bool comes back to
+    the host; nothing of the payload is copied.  Other payloads (numpy
+    arrays, lists, scalars) are tested on the host.  Either way the check
+    runs outside the guarded programs, so the extend and serve jaxprs are
+    untouched.  With observability on, each call bumps
+    ``resilience.admission.device`` and/or ``resilience.admission.host``
+    by the paths it took.
     """
     if not enabled():
         return
-    for a in arrays:
-        if a is None:
-            continue
-        arr = np.asarray(a, dtype=np.float64)
-        if not np.all(np.isfinite(arr)):
-            _trace.REGISTRY.inc("resilience.rejected_nonfinite")
-            _trace.emit({"type": "resilience", "action": "reject_nonfinite",
-                         "what": what,
-                         **({"tenant": str(tenant)} if tenant else {})})
-            raise NonFiniteObservationError(
-                f"non-finite {what} rejected at admission"
-                + (f" (tenant {tenant!r})" if tenant is not None else ""))
+    on_device = tuple(a for a in arrays if isinstance(a, jax.Array))
+    on_host = [a for a in arrays
+               if a is not None and not isinstance(a, jax.Array)]
+    # launched before the host tests below, which then overlap it
+    ok = _all_finite(on_device) if on_device else None
+    if _trace.enabled():
+        if on_device:
+            _trace.REGISTRY.inc("resilience.admission.device")
+        if on_host:
+            _trace.REGISTRY.inc("resilience.admission.host")
+    for a in on_host:
+        if not np.all(np.isfinite(np.asarray(a, dtype=np.float64))):
+            _reject(what, tenant)
+    if ok is not None and not bool(ok):
+        _reject(what, tenant)
+
+
+def _reject(what: str, tenant) -> None:
+    _trace.REGISTRY.inc("resilience.rejected_nonfinite")
+    _trace.emit({"type": "resilience", "action": "reject_nonfinite",
+                 "what": what,
+                 **({"tenant": str(tenant)} if tenant else {})})
+    raise NonFiniteObservationError(
+        f"non-finite {what} rejected at admission"
+        + (f" (tenant {tenant!r})" if tenant is not None else ""))
 
 
 # ---------------------------------------------------------------------------
@@ -119,8 +149,6 @@ def factor_ok(state, *, cond_limit: Optional[float] = None) -> bool:
     """Is the cached factorization serviceable?  Finite L diagonal,
     finite representers/residual, and (optionally) a condition-proxy
     bound from ``obs.health``."""
-    import jax.numpy as jnp
-
     data = state.data
     n = int(data.count)
     if n < 1:
@@ -175,8 +203,6 @@ def after_mutation(state) -> bool:
     residuals on a healthy stream are the iterative regime's business,
     and spurious jitter escalation would perturb exact-path answers.
     """
-    import jax.numpy as jnp
-
     data = state.data
     n = int(data.count)
     if n < 1:
@@ -221,8 +247,6 @@ def bf16_tripwire(state, *, limit: float = 0.05, n_points: int = 4) -> bool:
     the drift probe is ``obs.health.precision_drift`` at ``n_points``
     stored inputs.  Returns True when the wire tripped.
     """
-    import jax.numpy as jnp
-
     if getattr(state, "precision", "f32") != "bf16" or state.n < 1:
         return False
     cache = getattr(state, "_stream_cache", None)

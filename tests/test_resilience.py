@@ -27,6 +27,7 @@ import pytest
 
 from repro.core.fleet import GPFleet
 from repro.core.state import GPGState
+from repro.obs.trace import counter_value, use_obs
 from repro.resilience import (ChaosInjector, Journal, errors, guardrails,
                               replay_single, restore, take_snapshot)
 from repro.runtime.recovery import SimulatedFailure
@@ -96,6 +97,116 @@ def test_guardrails_disabled_admits_anything():
         x[0] = np.nan
         st.extend(x, np.ones(4))        # no admission check: NaN goes in
         assert st.n == 4
+
+
+KINDS = ("numpy", "device", "mixed")
+
+
+def _payload(kind, x, g):
+    """(x, g) as the input kind: numpy arrays, arrays on the default
+    device, or a device x beside a numpy g."""
+    if kind == "numpy":
+        return np.asarray(x), np.asarray(g)
+    if kind == "device":
+        return jnp.asarray(x), jnp.asarray(g)
+    return jnp.asarray(x), np.asarray(g)
+
+
+@pytest.mark.parametrize("where", ["x", "g"])
+@pytest.mark.parametrize("bad", [np.nan, np.inf])
+@pytest.mark.parametrize("kind", KINDS)
+def test_extend_rejects_nonfinite_by_input_kind(kind, bad, where):
+    st = _mk_state()
+    L, n = np.asarray(st.data.L).copy(), st.n
+    x, g = np.ones(4), np.ones(4)
+    (x if where == "x" else g)[1] = bad
+    rejected = counter_value("resilience.rejected_nonfinite")
+    with pytest.raises(errors.NonFiniteObservationError):
+        st.extend(*_payload(kind, x, g))
+    assert np.array_equal(np.asarray(st.data.L), L) and st.n == n
+    assert counter_value("resilience.rejected_nonfinite") == rejected + 1
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_fleet_and_server_reject_nonfinite_by_input_kind(kind):
+    bad = np.array([1.0, np.nan, 0.0])
+    fl = GPFleet("rbf", d=3, batch=2, window=4)
+    fl.join("a")
+    fl.join("b")
+    with pytest.raises(errors.NonFiniteObservationError, match="'b'"):
+        fl.extend({"a": _payload(kind, np.ones(3), np.ones(3)),
+                   "b": _payload(kind, bad, np.ones(3))})
+    assert fl.n("a") == 0 and fl.n("b") == 0
+
+    srv = _server()
+    req = srv.submit("t0", "extend", _payload(kind, bad, np.ones(3)))
+    assert req.done
+    assert isinstance(req.result, errors.NonFiniteObservationError)
+    assert "'t0'" in str(req.result)
+
+
+def test_check_finite_bf16_device_array():
+    a = jnp.array([1.0, 2.0, 3.0], dtype=jnp.bfloat16)
+    guardrails.check_finite(a)
+    for bad in (jnp.nan, jnp.inf):
+        with pytest.raises(errors.NonFiniteObservationError):
+            guardrails.check_finite(a.at[1].set(bad))
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_guardrails_off_launches_nothing(kind, monkeypatch):
+    launched = []
+    monkeypatch.setattr(guardrails, "_all_finite",
+                        lambda arrays: launched.append(arrays))
+    x = np.ones(4)
+    x[0] = np.nan
+    with guardrails.use_guardrails(False):
+        guardrails.check_finite(*_payload(kind, x, np.ones(4)))
+    assert launched == []
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_admission_counters_count_the_path(kind):
+    names = ("resilience.admission.device", "resilience.admission.host")
+    before = [counter_value(k) for k in names]
+    with use_obs(True):
+        guardrails.check_finite(*_payload(kind, np.ones(4), np.ones(4)))
+    want = {"numpy": [0, 1], "device": [1, 0], "mixed": [1, 1]}[kind]
+    assert [counter_value(k) - b for k, b in zip(names, before)] == want
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_admission_builds_one_executable_per_shape(kind):
+    # a shape no other test checks, so the cache starts without it
+    shape = {"numpy": (5, 7), "device": (7, 5), "mixed": (7, 7)}[kind]
+    size = guardrails._all_finite._cache_size()
+    for v in (1.0, 2.0):
+        guardrails.check_finite(*_payload(kind, np.full(shape, v),
+                                          np.full(shape, v)))
+    built = guardrails._all_finite._cache_size() - size
+    assert built == (0 if kind == "numpy" else 1)
+
+
+def test_sharded_extend_rejects_device_nan_1dev():
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.core.dist_state import ShardedGPGState
+    from repro.launch.mesh import make_d_mesh
+
+    d = 8
+    st = ShardedGPGState("rbf", d, window=3, mesh=make_d_mesh(), noise=1e-6)
+    r = np.random.RandomState(0)
+    st.extend(r.randn(d), r.randn(d))
+    L = np.asarray(st.data.base.L).copy()
+    put = NamedSharding(st.mesh, PartitionSpec(st.mesh.axis_names))
+    x = jax.device_put(jnp.asarray(r.randn(d)).at[3].set(jnp.nan), put)
+    g = jax.device_put(jnp.asarray(r.randn(d)), put)
+    device = counter_value("resilience.admission.device")
+    with use_obs(True), \
+            pytest.raises(errors.NonFiniteObservationError):
+        st.extend(x, g)
+    assert counter_value("resilience.admission.device") == device + 1
+    assert st.n == 1 and np.array_equal(np.asarray(st.data.base.L), L)
 
 
 # ---------------------------------------------------------------------------
